@@ -107,10 +107,8 @@ def train_flops(cfg: ModelConfig, batch: int, seq: int) -> float:
         logits, aux = fam.forward(p, bb, cfgp)
         return total_loss(logits, bb["labels"], aux)[0]
 
-    from repro.distributed.costs import cost_analysis_dict
-
     c = jax.jit(jax.grad(f)).lower(params, b).compile()
-    return float(cost_analysis_dict(c)["flops"])
+    return float(c.cost_analysis()["flops"])
 
 
 def save_result(name: str, payload) -> str:

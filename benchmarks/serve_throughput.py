@@ -602,9 +602,12 @@ def main():
     if jax.device_count() >= 8:
         main_mesh()
     else:
+        # A CPU sweep by design: the child is pinned to the CPU so it never
+        # competes with this process for an accelerator.
         import subprocess
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"
         subprocess.run([sys.executable, os.path.abspath(__file__),
                         "--mesh-sweep"], check=True, env=env)
 

@@ -37,7 +37,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -144,8 +143,8 @@ def alltoall_dispatch(params, xg: jax.Array, plan: RoutingPlan,
         return jax.lax.all_to_all(out, e_ax, split_axis=1, concat_axis=0,
                                   tiled=True)                  # (E, rows, M)
 
-    return shard_map(run, mesh=mesh, in_specs=specs, out_specs=grp,
-                     check_rep=False)(*args)
+    return jax.shard_map(run, mesh=mesh, in_specs=specs, out_specs=grp,
+                         check_vma=False)(*args)
 
 
 @register_dispatcher

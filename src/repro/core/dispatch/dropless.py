@@ -26,11 +26,12 @@ Expert parallelism: under an expert-sharded ``Rules`` mesh (same
 placement test as the ``alltoall`` backend), :func:`ragged_ep_dispatch`
 runs *explicit* EP — a padded variable-size ``lax.all_to_all`` over the
 ragged layout ships each expert shard exactly its own experts' sorted
-row segments (``jax.lax.ragged_all_to_all`` would drop the padding once
-available; the exchange is already O(load), never O(E*C)).  Without
-such a mesh, parallelism stays implicit (GSPMD over the sharded group
-axis, like ``gather``), weights replicated-or-sharded by the same rules
-as every other backend.
+row segments (``jax.lax.ragged_all_to_all`` would drop the padding; the
+exchange is already O(load), never O(E*C)).  Where the groups do not
+split over the device grid, the grouped FFN still runs under
+``shard_map`` (GSPMD cannot partition a Pallas kernel; see
+:func:`_ragged_ffn`); the gather and combine around it stay implicit
+(GSPMD over the sharded group axis, like ``gather``).
 """
 from __future__ import annotations
 
@@ -38,7 +39,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -46,7 +46,7 @@ from repro.core.context import MoEContext
 from repro.core.dispatch import register_dispatcher
 from repro.core.dispatch.alltoall import _expert_mesh_plan
 from repro.core.routers.base import RoutingPlan
-from repro.distributed.sharding import shard
+from repro.distributed.sharding import active_rules, shard
 from repro.kernels.moe_dropless import ops as dropless_ops
 from repro.kernels.moe_dropless.ops import pick_block_rows
 
@@ -143,8 +143,47 @@ def ragged_ep_dispatch(params, xg: jax.Array, plan: RoutingPlan,
     args = (p_local, xg, rag.token, rag.gate, rag.expert_offsets,
             rag.block_expert)
     specs = (w_spec, grp, grp, grp, grp, grp)
-    return shard_map(run, mesh=mesh, in_specs=specs, out_specs=grp,
-                     check_rep=False)(*args)
+    return jax.shard_map(run, mesh=mesh, in_specs=specs, out_specs=grp,
+                         check_vma=False)(*args)
+
+
+def _ragged_ffn(xs, block_expert, w, act: str, bx: int, rules):
+    """The grouped FFN over sorted rows, under whatever mesh is active.
+
+    GSPMD cannot partition a Mosaic kernel, so on a multi-device mesh the
+    call runs under ``shard_map`` with the rows replicated.  When the
+    experts are sharded, each expert shard runs the row blocks of its own
+    experts (other blocks point at a local expert and are zeroed) and a
+    psum over the expert axis combines them: every row comes from exactly
+    one shard, so the sum only adds zeros.  This is the path of a step
+    with too few groups to split for :func:`ragged_ep_dispatch` — a decode
+    step routes one group.
+    """
+    def ffn(p, x, be):
+        return dropless_ops.ragged_ffn(x, be, p["up"], p.get("gate"),
+                                       p["down"], act, block_x=bx)
+
+    if rules is None or rules.mesh.size == 1:
+        return ffn(w, xs, block_expert)
+    mesh = rules.mesh
+    e_ax = rules.params.get("expert")
+    E = w["up"].shape[0]
+    if isinstance(e_ax, tuple) or e_ax is None or E % mesh.shape[e_ax]:
+        e_ax = None                               # experts replicated
+    w_spec = {k: P(e_ax) for k in w}
+
+    def run(p, x, be):
+        if e_ax is None:
+            return ffn(p, x, be)
+        epl = p["up"].shape[0]
+        lo = jax.lax.axis_index(e_ax) * epl
+        mine = (be >= lo) & (be < lo + epl)
+        y = ffn(p, x, jnp.where(mine, be - lo, 0))
+        y = jnp.where(jnp.repeat(mine, bx)[:, None], y, 0)
+        return jax.lax.psum(y, e_ax)
+
+    return jax.shard_map(run, mesh=mesh, in_specs=(w_spec, P(), P()),
+                         out_specs=P())(w, xs, block_expert)
 
 
 def dropless_dispatch(params, xg: jax.Array, plan: RoutingPlan,
@@ -162,11 +201,10 @@ def dropless_dispatch(params, xg: jax.Array, plan: RoutingPlan,
     xs = jnp.take_along_axis(xg, tok[..., None], axis=1).astype(dt)
     xs = shard(xs, "groups", None, None)
 
-    out = dropless_ops.ragged_ffn(
-        xs.reshape(G * R, M), rag.block_expert.reshape(-1),
-        params["up"].astype(dt),
-        params["gate"].astype(dt) if "gate" in params else None,
-        params["down"].astype(dt), cfg.ffn_activation, block_x=block_rows)
+    w = {k: params[k].astype(dt) for k in ("up", "gate", "down")
+         if k in params}
+    out = _ragged_ffn(xs.reshape(G * R, M), rag.block_expert.reshape(-1), w,
+                      cfg.ffn_activation, block_rows, active_rules())
     out = out.reshape(G, R, M)
 
     # Empty rows (padding / capacity-dropped choices) carry gate 0, so

@@ -19,18 +19,6 @@ from typing import Dict
 from repro.configs.base import ModelConfig, ShapeConfig
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """`compiled.cost_analysis()` across jax versions: some return the
-    properties dict directly, some a one-element list of it."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        if not cost:
-            raise RuntimeError(
-                "compiled.cost_analysis() returned no data on this backend")
-        cost = cost[0]
-    return cost
-
-
 def moe_rows_per_token(m, tokens_per_group: int) -> float:
     """Expert-buffer rows processed per routed token (E*C / T_g).
 

@@ -1,21 +1,45 @@
-"""Three-term roofline model for TPU v5e (target hardware).
+"""Three-term roofline model, with the peaks of the chip it targets.
 
-  t_compute    = HLO_FLOPs  / (chips * 197e12)   bf16 peak / chip
-  t_memory     = HLO_bytes  / (chips * 819e9)    HBM bandwidth / chip
-  t_collective = coll_bytes / (chips * 50e9)     per-link ICI bandwidth
+  t_compute    = HLO_FLOPs  / (chips * peak FLOP/s)
+  t_memory     = HLO_bytes  / (chips * HBM bytes/s)
+  t_collective = coll_bytes / (chips * ICI bytes/s per link)
 
-Inputs come from the dry-run: ``compiled.cost_analysis()`` (flops, bytes
-accessed) and the HLO collective parser.  MODEL_FLOPS = 6*N_active*D
-(dense: N_active = N) gives the useful-compute ratio.
+Peaks come from :data:`CHIP_PEAKS`, keyed by ``device_kind`` as JAX
+reports it; a kind that is not in the table raises rather than borrowing
+another chip's numbers.  Inputs come from the dry-run:
+``compiled.cost_analysis()`` (flops, bytes accessed) and the HLO
+collective parser.  MODEL_FLOPS = 6*N_active*D (dense: N_active = N)
+gives the useful-compute ratio.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 / chip (v5e)
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # interconnect bytes/s per link
+    hbm_bytes: float    # HBM capacity per chip
+
+
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s interconnect per chip (4 links of 50 GB/s).
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                             hbm_bytes=16e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -24,19 +48,27 @@ class Roofline:
     bytes_accessed: float
     collective_bytes: float
     chips: int
+    device_kind: str
     model_flops: Optional[float] = None   # 6 * N_active * tokens
+
+    def __post_init__(self):
+        chip_peaks(self.device_kind)   # an unknown chip fails here
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS)
+        return self.flops / (self.chips * self.peaks.flops)
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / (self.chips * HBM_BW)
+        return self.bytes_accessed / (self.chips * self.peaks.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / (self.chips * ICI_BW)
+        return self.collective_bytes / (self.chips * self.peaks.ici_bw)
 
     @property
     def dominant(self) -> str:
@@ -60,7 +92,8 @@ class Roofline:
         useful FLOPs / (chips * peak * bound_time)."""
         if not self.model_flops or self.bound_time == 0:
             return None
-        return self.model_flops / (self.chips * PEAK_FLOPS * self.bound_time)
+        return self.model_flops / (self.chips * self.peaks.flops
+                                   * self.bound_time)
 
     def to_dict(self) -> Dict:
         return {
@@ -68,6 +101,7 @@ class Roofline:
             "bytes_accessed": self.bytes_accessed,
             "collective_bytes": self.collective_bytes,
             "chips": self.chips,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "t_compute": self.t_compute,
             "t_memory": self.t_memory,

@@ -18,9 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5 releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -89,7 +86,7 @@ def decode_attention_kernel(q, k, v, lengths, *, block_kv: int = 1024,
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k, v)
@@ -170,7 +167,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)
@@ -185,11 +182,14 @@ def _quant_paged_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref, v_ref,
                         scale, bs, n_b, decode):
     """``_paged_kernel`` with in-register dequant: the K/V tiles arrive
     as int8 codes (half the HBM->VMEM bytes of bf16 — the decode
-    bandwidth win), their (1, 1) scale blocks ride the same
-    ``tbl[i, b]`` index map, and ``value = decode(code) * scale`` is
-    materialised in VMEM registers inside the online-softmax loop —
-    never written back anywhere."""
+    bandwidth win), the block's per-head scale row rides the same
+    ``tbl[i, b]`` index map into SMEM (a ``(1, 1, Hkv)`` block over the
+    scales viewed as ``(P, 1, Hkv)``: a ``(1, 1)`` block of ``(P, Hkv)``
+    breaks the TPU tiling rule), this head's scalar is read from it, and
+    ``value = decode(code) * scale`` is materialised in VMEM registers
+    inside the online-softmax loop — never written back anywhere."""
     i = pl.program_id(0)
+    h = pl.program_id(1)
     b = pl.program_id(2)
 
     @pl.when(b == 0)
@@ -202,7 +202,7 @@ def _quant_paged_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref, v_ref,
     @pl.when(b * bs < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-        k = decode(k_ref[0, 0]) * ks_ref[0, 0]               # (bs, D) f32
+        k = decode(k_ref[0, 0]) * ks_ref[0, 0, h]            # (bs, D) f32
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, bs)
         cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -212,7 +212,7 @@ def _quant_paged_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref, v_ref,
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        v = decode(v_ref[0, 0]) * vs_ref[0, 0]               # (bs, D) f32
+        v = decode(v_ref[0, 0]) * vs_ref[0, 0, h]            # (bs, D) f32
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -245,9 +245,11 @@ def quantized_paged_decode_attention_kernel(q, k_pool, v_pool, k_scales,
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda i, h, b, tbl, lens: (i, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, D), lambda i, h, b, tbl, lens: (tbl[i, b], h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, h, b, tbl, lens: (tbl[i, b], h)),
+            pl.BlockSpec((1, 1, Hkv), lambda i, h, b, tbl, lens: (tbl[i, b], 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, bs, D), lambda i, h, b, tbl, lens: (tbl[i, b], h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, h, b, tbl, lens: (tbl[i, b], h)),
+            pl.BlockSpec((1, 1, Hkv), lambda i, h, b, tbl, lens: (tbl[i, b], 0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda i, h, b, tbl, lens: (i, h, 0, 0)),
         scratch_shapes=[
@@ -260,8 +262,9 @@ def quantized_paged_decode_attention_kernel(q, k_pool, v_pool, k_scales,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, k_scales, v_pool, v_scales)
+      q, k_pool, k_scales.reshape(-1, 1, Hkv), v_pool,
+      v_scales.reshape(-1, 1, Hkv))

@@ -146,12 +146,11 @@ def sharded_paged_update_attention(q, k, v, k_pool, v_pool, write_blocks,
     another shard's blocks, and no unsharded ``(num_blocks, ...)`` pool
     appears inside the mapped computation.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dx = P(axis)
-    fn = shard_map(paged_update_attention, mesh=mesh, in_specs=(dx,) * 9,
-                   out_specs=(dx, dx, dx), check_rep=False)
+    fn = jax.shard_map(paged_update_attention, mesh=mesh, in_specs=(dx,) * 9,
+                       out_specs=(dx, dx, dx), check_vma=False)
     return fn(q, k, v, k_pool, v_pool, write_blocks, write_offsets,
               block_tables, lengths)
 
@@ -165,13 +164,12 @@ def sharded_quantized_paged_update_attention(q, k, v, k_pool, v_pool,
     mesh's data axis — the same leading-dimension partitioning as
     :func:`sharded_paged_update_attention`, with the scale pools sharded
     alongside their code pools (both are keyed by shard-local ids)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dx = P(axis)
     body = partial(quantized_paged_update_attention, policy=policy)
-    fn = shard_map(body, mesh=mesh, in_specs=(dx,) * 11,
-                   out_specs=(dx,) * 5, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(dx,) * 11,
+                       out_specs=(dx,) * 5, check_vma=False)
     return fn(q, k, v, k_pool, v_pool, k_scales, v_scales, write_blocks,
               write_offsets, block_tables, lengths)
 

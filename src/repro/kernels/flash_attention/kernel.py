@@ -17,9 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5 releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -93,7 +90,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

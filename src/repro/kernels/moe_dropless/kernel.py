@@ -30,9 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5 releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _act(h, g, activation: str):
     if g is not None:
@@ -117,8 +114,12 @@ def ragged_ffn_kernel(x: jax.Array, block_expert: jax.Array, w_up: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, M), x.dtype),
-        compiler_params=_CompilerParams(
+        # under shard_map the output varies over whatever axes the
+        # operands vary over
+        out_shape=jax.ShapeDtypeStruct(
+            (N, M), x.dtype,
+            vma=frozenset().union(*(jax.typeof(a).vma for a in args))),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_expert, *args)

@@ -43,14 +43,46 @@ def padded_rows(n_choices: int, num_experts: int, block_rows: int) -> int:
     return -(-n // block_rows) * block_rows
 
 
+# Mosaic's default scoped-VMEM limit is 16 MiB; the budget leaves room
+# for the compiler's own scratch.  Compiled for v5e: a 13 MiB working set
+# (granite widths, f32, bx=128, bi=256) fits, 18.3 MiB (bx=8, bi=512)
+# is refused.
+VMEM_BUDGET_BYTES = 14 * 2**20
+
+
+def kernel_vmem_bytes(block_x: int, block_i: int, d_model: int, n_mats: int,
+                      x_bytes: int, w_bytes: int) -> int:
+    """Per-step VMEM working set of ``ragged_ffn_kernel``: double-buffered
+    x / out row tiles and weight tiles, the f32 accumulator, and the f32
+    (bx, bi) intermediates."""
+    rows = 2 * 2 * block_x * d_model * x_bytes + block_x * d_model * 4
+    weights = 2 * n_mats * d_model * block_i * w_bytes
+    inter = 2 * block_x * block_i * 4
+    return rows + weights + inter
+
+
+def pick_block_i(block_x: int, d_model: int, d_ff: int, n_mats: int,
+                 x_bytes: int, w_bytes: int, max_block: int = 512) -> int:
+    """Largest intermediate tile <= max_block that divides d_ff, is
+    lane-aligned (a multiple of 128, or d_ff itself) and whose working
+    set fits ``VMEM_BUDGET_BYTES``.  When none fits, the smallest aligned
+    tile is returned and the compiler has the last word."""
+    tiles = [t for t in range(d_ff, 0, -1)
+             if d_ff % t == 0 and (t == d_ff or t % 128 == 0)]
+    tiles = [t for t in tiles if t <= max_block] or tiles[-1:]
+    for t in tiles:
+        if kernel_vmem_bytes(block_x, t, d_model, n_mats, x_bytes,
+                             w_bytes) <= VMEM_BUDGET_BYTES:
+            return t
+    return tiles[-1]
+
+
 def _run(x, block_expert, w_up, w_gate, w_down, activation, block_x, block_i):
     if jax.default_backend() != "tpu":
         return ragged_ffn_ref(x, block_expert, w_up, w_gate, w_down, activation)
-    I = w_up.shape[-1]
-    bi = min(block_i, I)
-    while bi > 1 and I % bi:
-        bi //= 2
-    # loop invariant: bi divides I on exit (worst case bi == 1)
+    bi = pick_block_i(block_x, x.shape[1], w_up.shape[-1],
+                      3 if w_gate is not None else 2, x.dtype.itemsize,
+                      w_up.dtype.itemsize, max_block=block_i)
     return ragged_ffn_kernel(x, block_expert, w_up, w_gate, w_down, activation,
                              block_x=block_x, block_i=bi)
 
@@ -95,4 +127,5 @@ def ragged_ffn(x: jax.Array, block_expert: jax.Array, w_up: jax.Array,
                        block_x, block_i)
 
 
-__all__ = ["ragged_ffn", "ragged_ffn_ref", "pick_block_rows", "padded_rows"]
+__all__ = ["ragged_ffn", "ragged_ffn_ref", "pick_block_rows", "padded_rows",
+           "pick_block_i", "kernel_vmem_bytes"]

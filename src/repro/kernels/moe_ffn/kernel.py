@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5 releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _act(h, g, activation: str):
     if g is not None:
@@ -104,7 +101,7 @@ def moe_ffn_kernel(x: jax.Array, w_up: jax.Array, w_gate: Optional[jax.Array],
         out_specs=pl.BlockSpec((1, bx, M), lambda e, xb, ib: (e, xb, 0)),
         out_shape=jax.ShapeDtypeStruct((E, X, M), x.dtype),
         scratch_shapes=[pltpu.VMEM((bx, M), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -34,7 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.configs.registry import ARCH_IDS, ALL_IDS, get_config
 from repro.configs.shapes import SHAPES, shapes_for
-from repro.distributed.costs import bytes_for, cost_analysis_dict, flops_for
+from repro.distributed.costs import bytes_for, flops_for
 from repro.distributed.hlo import collective_bytes, op_histogram
 from repro.distributed.roofline import (
     Roofline, model_flops_forward, model_flops_train)
@@ -49,6 +49,9 @@ from repro.optim import make_optimizer, warmup_constant
 from repro.optim.zero import zero1_shardings
 from repro.train.state import TrainState
 from repro.train.trainer import make_train_step
+
+# The chip the production mesh is made of; its peaks set the roofline.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def active_param_count(cfg: ModelConfig, specs) -> float:
@@ -225,7 +228,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
@@ -242,6 +245,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         bytes_accessed=a_bytes,
         collective_bytes=float(coll["total"]),
         chips=chips,
+        device_kind=TARGET_DEVICE_KIND,
         model_flops=mf,
     )
     result = {
@@ -259,7 +263,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "alias_bytes_per_device": mem.alias_size_in_bytes,
             "peak_bytes_per_device": (mem.argument_size_in_bytes
                                       + mem.temp_size_in_bytes),
-            "fits_16gb": (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 16e9,
+            "fits_16gb": (mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+                         < rl.peaks.hbm_bytes,
         },
         "collectives": coll,
         "roofline": rl.to_dict(),

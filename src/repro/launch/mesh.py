@@ -9,6 +9,15 @@ the inter-pod (DCI) links.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place arrays
+    with ``with_sharding_constraint``, which only names Auto axes (the
+    default since JAX 0.7 is Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,12 +30,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     if env:
         shape = tuple(int(x) for x in env.split(","))
         assert len(shape) == len(axes), (shape, axes)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many devices this process has."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_serve_mesh(spec):

@@ -16,6 +16,7 @@ synthetic prompts or a request trace.
 from __future__ import annotations
 
 import argparse
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -23,6 +24,7 @@ import numpy as np
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ServeConfig, SLOConfig, SpecConfig
 from repro.configs.registry import ALL_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_family
 from repro.nn import abstract, init as init_params
 from repro.serving.continuous import ContinuousEngine
@@ -37,6 +39,16 @@ from repro.serving.trace import (
     synthetic_priority,
     synthetic_trace,
 )
+
+
+class ServeResult(NamedTuple):
+    """What :func:`main` served: the engine, the trace (None in batch
+    mode), the generated tokens (``{uid: tokens}`` for a trace, a
+    ``(batch, gen)`` array in batch mode) and the run's stats."""
+    engine: Any
+    requests: Optional[List]
+    outputs: Any
+    stats: Dict[str, float]
 
 
 def parse_mesh(text: str):
@@ -186,6 +198,7 @@ def main(argv=None):
                          "run into this directory (view with TensorBoard "
                          "or Perfetto)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh_spec = None
     if args.mesh is not None:
@@ -280,7 +293,7 @@ def main(argv=None):
             _write_obs(engine, args)
         print("generated:", np.asarray(toks)[:, :16])
         print({k: round(float(v), 4) for k, v in stats.items()})
-        return
+        return ServeResult(engine, None, toks, stats)
 
     # trace-driven serving
     if args.trace is not None:
@@ -306,9 +319,9 @@ def main(argv=None):
 
     if args.engine == "static":
         engine = ServingEngine(cfg, params, max_len=static_len)
-        _, stats = run_trace_static(engine, requests, args.batch,
-                                    temperature=args.temperature,
-                                    seed=args.seed)
+        outputs, stats = run_trace_static(engine, requests, args.batch,
+                                          temperature=args.temperature,
+                                          seed=args.seed)
     else:
         slo = (SLOConfig(preemption=args.slo_preempt,
                          host_blocks=args.host_blocks, shed=args.slo_shed)
@@ -332,7 +345,7 @@ def main(argv=None):
         if args.profile_dir:
             jax.profiler.start_trace(args.profile_dir)
         try:
-            _, stats = engine.run(requests, on_finish=stream)
+            outputs, stats = engine.run(requests, on_finish=stream)
         finally:
             if args.profile_dir:
                 jax.profiler.stop_trace()
@@ -354,6 +367,7 @@ def main(argv=None):
         if line:
             print(line)
     print(latency_line(stats))
+    return ServeResult(engine, requests, outputs, stats)
 
 
 if __name__ == "__main__":

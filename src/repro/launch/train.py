@@ -23,6 +23,7 @@ from repro.configs.registry import ALL_IDS, get_config, get_smoke_config
 from repro.data.pipeline import make_pipeline
 from repro.distributed.fault import StepWatchdog, run_with_restarts
 from repro.distributed.sharding import make_rules, param_shardings, use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.registry import get_family
 from repro.nn import init as init_params
@@ -85,7 +86,11 @@ def main(argv=None):
     ap.add_argument("--data", default=1, type=int, help="data mesh axis")
     ap.add_argument("--model", default=1, type=int, help="model mesh axis")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--max-restarts", type=int, default=3)
+    ap.add_argument("--max-restarts", type=int, default=3,
+                    help="restarts from the latest checkpoint after a failed "
+                         "step (needs --ckpt-dir: without a checkpoint a "
+                         "restart would replay the same steps and fail the "
+                         "same way)")
     # observability (repro.obs)
     ap.add_argument("--trace-out", default=None,
                     help="write per-train-step spans here: Chrome-trace "
@@ -97,6 +102,7 @@ def main(argv=None):
                     help="capture a jax.profiler device trace of the train "
                          "loop into this directory")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = build(args)
     fam = get_family(cfg)
@@ -184,7 +190,8 @@ def main(argv=None):
         jax.profiler.start_trace(args.profile_dir)
     try:
         with mesh:
-            run_with_restarts(loop, resume_step, max_restarts=args.max_restarts)
+            run_with_restarts(loop, resume_step,
+                              max_restarts=args.max_restarts if ckpt else 0)
     finally:
         if args.profile_dir:
             jax.profiler.stop_trace()

@@ -507,6 +507,19 @@ class ContinuousEngine:
             self._step_fn = jax.jit(rec_step, donate_argnums=(1,))
             self._reset_fn = jax.jit(reset_slot, donate_argnums=(0,))
 
+    def lower_step(self):
+        """Lower the paged step at its decode-only row shape over the
+        engine's current params and pools — the program :meth:`step`
+        runs, for reading its compiled text."""
+        serve, cache = self.serve, self.cache
+        b = _row_buffers(serve.max_slots, serve.blocks_per_slot,
+                         cache.garbage_block)
+        return self._step_fn.lower(
+            self.params, cache.k_pool, cache.v_pool, b["tokens"],
+            b["ctx_ids"], b["positions"], b["lengths"], b["row_tables"],
+            b["wb"], b["wo"], b["slots"], self._key, cache.k_scales,
+            cache.v_scales)
+
     # -- observability ------------------------------------------------------
 
     @property

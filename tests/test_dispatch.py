@@ -713,6 +713,52 @@ def test_dropless_on_mesh_conserves_tokens(run_sub):
 
 
 @pytest.mark.skipif(jax.device_count() >= 8,
+                    reason="the subprocess variant belongs to the "
+                           "single-device CI job")
+def test_dropless_one_group_on_mesh_matches_single_device(run_sub):
+    """(2, 4) mesh, one routing group (a serving step's shape): explicit EP
+    cannot split the group, so the grouped FFN runs under shard_map with
+    the rows replicated and the expert shards psum'd — GSPMD cannot
+    partition the TPU kernel.  Outputs and grads equal the single-device
+    dropless layer exactly."""
+    code = """
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs.base import ModelConfig, MoEConfig
+    from repro.core.moe import moe_ffn_apply, moe_ffn_specs
+    from repro.distributed.sharding import make_rules, use_rules
+    from repro.launch.mesh import make_debug_mesh
+    from repro.nn import init
+
+    mesh = make_debug_mesh(2, 4)
+    cfg = ModelConfig(d_model=32, d_ff=48, dtype="float32",
+                      moe=MoEConfig(num_experts=8, routing="topk", top_k=2,
+                                    group_size=64, impl="dropless",
+                                    capacity_factor=None))
+    rules = make_rules(cfg, mesh)
+    params = init(moe_ffn_specs(cfg), jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 24, 32))   # G = 1
+
+    def loss(r):
+        def f(p, xx):
+            with use_rules(r):
+                y = moe_ffn_apply(p, xx, cfg)[0]
+            return jnp.sum(y ** 2), y
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+    (_, y0), g0 = loss(None)(params, x)
+    with mesh:
+        (_, y1), g1 = loss(rules)(params, x)
+        text = loss(rules).lower(params, x).as_text()
+    assert "all_to_all" not in text and "manual_computation" in text
+    np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    print("one-group-mesh-ok")
+    """
+    assert "one-group-mesh-ok" in run_sub(code)
+
+
+@pytest.mark.skipif(jax.device_count() >= 8,
                     reason="multi-device parent runs the in-process ragged-EP "
                            "tests instead; the subprocess variant belongs to "
                            "the single-device CI job")

@@ -17,7 +17,8 @@ def test_rules_divisibility_fallbacks(run_sub):
     import jax
     from repro.configs.base import ModelConfig, MoEConfig
     from repro.distributed.sharding import make_rules
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     # 6 experts / 5 heads do not divide the 4-way model axis (the granite-
     # on-16 situation, scaled to this 8-device test mesh)
     cfg = ModelConfig(num_heads=5, num_kv_heads=2, head_dim=10, d_model=60,
@@ -51,6 +52,7 @@ def test_pjit_train_step_multidevice_matches_single(run_sub):
     from repro.train.state import init_train_state
     from repro.train.trainer import make_train_step
     from repro.distributed.sharding import make_rules, param_shardings, use_rules
+    from repro.launch.mesh import make_mesh
 
     cfg = ModelConfig(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
                       d_ff=64, vocab_size=128, dtype="float32",
@@ -70,7 +72,7 @@ def test_pjit_train_step_multidevice_matches_single(run_sub):
     s1, m1 = jax.jit(step)(s1, batch)
 
     # 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules(cfg, mesh)
     p_shard = param_shardings(fam.specs(cfg), rules)
     def wrapped(state, b):
@@ -99,13 +101,14 @@ def test_elastic_checkpoint_reshard(run_sub):
     import jax, jax.numpy as jnp, numpy as np, tempfile, os
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.checkpointer import Checkpointer
+    from repro.launch.mesh import make_mesh
     d = tempfile.mkdtemp()
-    mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh1 = make_mesh((2, 4), ("data", "model"))
     x = jnp.arange(64.0).reshape(8, 8)
     xs = jax.device_put(x, NamedSharding(mesh1, P("data", "model")))
     ck = Checkpointer(d)
     ck.save(1, {"x": xs})
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = make_mesh((4, 2), ("data", "model"))
     sh2 = {"x": NamedSharding(mesh2, P("model", "data"))}
     got = ck.restore(1, {"x": jax.eval_shape(lambda: x)}, shardings=sh2)
     np.testing.assert_array_equal(np.asarray(got["x"]), np.asarray(x))
@@ -120,7 +123,8 @@ def test_hlo_collective_parser_trip_counts(run_sub):
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.distributed.hlo import collective_bytes
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     def f(x, ws):
         def body(c, w):
             y = c @ w
@@ -159,9 +163,22 @@ def test_analytic_flops_vs_unrolled_cost_analysis(arch):
         logits, aux = fam.forward(p, b, cfg)
         return total_loss(logits, b["labels"], aux)[0]
 
-    from repro.distributed.costs import cost_analysis_dict
     compiled = jax.jit(jax.grad(f)).lower(params, batch).compile()
-    measured = cost_analysis_dict(compiled)["flops"]
+    measured = compiled.cost_analysis()["flops"]
     analytic = flops_for(cfg, shape)
     ratio = analytic / measured
     assert 0.6 < ratio < 1.7, (arch, ratio)
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the table for the named chip; an unknown chip is
+    an error, never a borrowed default."""
+    from repro.distributed.roofline import CHIP_PEAKS, Roofline
+
+    rl = Roofline(flops=197e12, bytes_accessed=819e9, collective_bytes=0.0,
+                  chips=1, device_kind="TPU v5 lite")
+    assert rl.t_compute == rl.t_memory == 1.0
+    assert rl.peaks is CHIP_PEAKS["TPU v5 lite"]
+    with pytest.raises(ValueError, match="no published peaks"):
+        Roofline(flops=1.0, bytes_accessed=1.0, collective_bytes=0.0,
+                 chips=1, device_kind="TPU v9 imaginary")

@@ -151,8 +151,8 @@ def test_grad_compression_int8_error_feedback_converges():
     pipe = SyntheticLM(cfg.vocab_size, batch=8, seq_len=32, seed=3)
     batch = {k: jnp.asarray(v) for k, v in pipe.batch_at(0).items()}
     first = last = None
-    # 35 steps: at 25 this sits right on the 10% bar on some jax/XLA
-    # versions (9.8% on jax 0.4.37 CPU) — headroom, not a weaker claim.
+    # 35 steps: at 25 this sits right on the 10% bar — headroom, not a
+    # weaker claim.
     for _ in range(35):
         state, m = step(state, batch)
         first = first if first is not None else float(m["loss"])
