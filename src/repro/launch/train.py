@@ -31,6 +31,9 @@ from repro.optim import make_optimizer, warmup_constant
 from repro.train.state import TrainState, init_train_state
 from repro.train.trainer import make_train_step
 
+# the wall clock of the logged step time and token rate
+_clock = time.perf_counter
+
 
 def build(args):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -153,26 +156,33 @@ def main(argv=None):
             state = ckpt.restore(start_step, jax.eval_shape(lambda: state))
         t_tokens = args.batch * args.seq
         reg = obs.metrics
+        # a log step reports the interval since the previous one (or the
+        # loop's start), read after its device_get: dispatch is
+        # asynchronous, so one step's own host time says nothing
+        t_log, tokens, steps = _clock(), 0, 0
         for step in range(start_step, args.steps):
-            t0 = time.time()
             batch = {k: jnp.asarray(v) for k, v in pipeline.batch_at(step).items()}
             with obs.tracer.span("train_step", cat="train", step=step,
                                  tokens=t_tokens):
                 state, metrics = jit_step(state, batch)
             reg.counter("train_steps_total").inc()
             reg.counter("train_tokens_total").inc(t_tokens)
+            tokens, steps = tokens + t_tokens, steps + 1
             if step % args.log_every == 0 or step == args.steps - 1:
                 m = {k: float(np.mean(jax.device_get(v))) for k, v in metrics.items()}
-                dt = time.time() - t0
+                now = _clock()
+                interval = now - t_log
+                rate, dt = tokens / interval, interval / steps
+                t_log, tokens, steps = now, 0, 0
                 watchdog.observe(dt)
                 m.update(step=step, step_time_s=round(dt, 3),
-                         tokens_per_s=round(t_tokens / dt, 1))
+                         tokens_per_s=round(rate, 1))
                 logs.append(m)
                 for key in ("loss", "ce", "moe_cv", "moe_dropped_fraction",
                             "moe_aux_loss", "moe_z_loss"):
                     if key in m:
                         reg.gauge(f"train_{key}").set(m[key])
-                reg.gauge("train_tokens_per_s").set(m["tokens_per_s"])
+                reg.gauge("train_tokens_per_s").set(rate)
                 reg.histogram("train_step_ms").observe(dt * 1e3)
                 if args.metrics_out:
                     obs.metrics_row(step=step)

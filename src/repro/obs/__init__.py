@@ -6,7 +6,8 @@ one per :class:`~repro.serving.continuous.ContinuousEngine`, shared with
 its :class:`~repro.serving.scheduler.Scheduler` and
 :class:`~repro.serving.slo.swap.SwapManager` so every component
 publishes into the same registry and trace.  The registry is always on
-(a few dict updates per engine step); the tracer is opt-in
+(a few dict updates per engine step); the tracer's complete spans always
+reach a running ``jax.profiler`` session, its ring buffer is opt-in
 (``tracing=True`` / ``--trace-out``).
 
 Span taxonomy, metric names and labels: ``docs/observability.md``.

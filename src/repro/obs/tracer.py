@@ -1,25 +1,29 @@
-"""Low-overhead span tracer emitting Chrome-trace events.
+"""Low-overhead span tracer: the one span system of ``repro``.
 
-The tracer is a preallocated ring buffer of event dicts over a
-monotonic clock (``time.perf_counter_ns``).  Three event shapes cover
-the serving taxonomy (see ``docs/observability.md``):
+Every complete span is a ``jax.profiler.TraceAnnotation``, so it lands
+in a ``jax.profiler`` trace on the same clock as the device's
+operations, its args attached as stats at span exit; with no profiler
+session on, that costs one annotation enter/exit.  Opt-in, the same
+spans (and the async spans and instants below) are also recorded into
+a preallocated ring buffer of Chrome-trace events over a monotonic
+clock (``time.perf_counter_ns``).  Three event shapes cover the
+serving taxonomy (see ``docs/observability.md``):
 
 * **Complete spans** (``ph="X"``) — synchronous work with a duration:
   one per engine step (``engine_step``, args carry the step kind and
-  live/padded row split).
+  live/padded row split) with its phase spans nested inside.
 * **Async spans** (``ph="b"``/``"e"``, paired by ``(cat, id)``) — the
   request lifecycle: an outer ``request`` span per uid with nested
   phase spans (``queued`` → ``prefill`` → ``decode`` →
   ``preempted`` → …) sharing the same async id, which is exactly how
-  Perfetto renders nesting.
+  Perfetto renders nesting.  Ring only: the profiler has no async track.
 * **Instants** (``ph="i"``) — point events: ``preempt``, ``restore``,
-  ``recompile``.
+  ``recompile``.  Ring only.
 
-When disabled every emit path is a constant-time no-op (one attribute
-check); ``span()`` returns a shared null context manager, so
-instrumentation can stay in place unconditionally.  The ring buffer
-never grows: past ``capacity`` events the oldest are overwritten and
-``dropped_events`` counts the loss.
+With the ring disabled every async/instant emit path is a constant-time
+no-op (one attribute check), so instrumentation can stay in place
+unconditionally.  The ring buffer never grows: past ``capacity`` events
+the oldest are overwritten and ``dropped_events`` counts the loss.
 
 Export: :meth:`write_chrome_trace` writes a Perfetto-loadable
 ``{"traceEvents": [...]}`` JSON; :meth:`write_jsonl` writes the same
@@ -27,16 +31,28 @@ events one per line.
 """
 from __future__ import annotations
 
+import functools
 import json
 import time
 from typing import Dict, List, Optional
 
 
-class _Span:
-    """Context manager for one ``ph="X"`` complete span.  ``args`` is
-    mutable until exit — fill in values discovered mid-span."""
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    ``repro.obs`` imports without jax."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+
+class _Span:
+    """Context manager for one complete span: a profiler annotation
+    always, a ``ph="X"`` ring event when the ring is enabled.  ``args``
+    is mutable until exit — fill in values discovered mid-span; the
+    profiler receives them all at exit (its encoding cuts a value at
+    the first comma, so give sequences as space-separated strings)."""
+
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr: "SpanTracer", name: str, cat: str, args: Dict):
         self._tr = tr
@@ -45,27 +61,22 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter_ns()
+        self._ann = _trace_annotation()(self.name)
+        self._ann.__enter__()
+        if self._tr.enabled:
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        t1 = time.perf_counter_ns()
-        self._tr._emit({"name": self.name, "cat": self.cat, "ph": "X",
-                        "ts": self._t0 / 1e3, "dur": (t1 - self._t0) / 1e3,
-                        "pid": 0, "tid": 0, "args": self.args})
-
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return None
-
-
-_NULL = _NullSpan()
+        if self._tr.enabled:
+            t1 = time.perf_counter_ns()
+            self._tr._emit({"name": self.name, "cat": self.cat, "ph": "X",
+                            "ts": self._t0 / 1e3,
+                            "dur": (t1 - self._t0) / 1e3,
+                            "pid": 0, "tid": 0, "args": self.args})
+        if self.args and self._ann.is_enabled():
+            self._ann.set_metadata(**self.args)
+        self._ann.__exit__(*exc)
 
 
 class SpanTracer:
@@ -86,11 +97,10 @@ class SpanTracer:
     def _ts(self) -> float:
         return time.perf_counter_ns() / 1e3          # microseconds
 
-    def span(self, name: str, cat: str = "engine", **args):
+    def span(self, name: str, cat: str = "engine", **args) -> _Span:
         """``with tracer.span("engine_step", kind="mixed") as sp: ...``
-        — ``sp`` is None when tracing is disabled."""
-        if not self.enabled:
-            return _NULL
+        — a profiler annotation whether or not the ring is enabled, so
+        ``sp.args`` takes late values either way."""
         return _Span(self, name, cat, args)
 
     def begin(self, cat: str, id: object, name: str, **args) -> None:

@@ -124,22 +124,24 @@ def _paged_block(bp, x, cfg: ModelConfig, *, moe_layer: bool, positions,
     N = x.shape[1]
     h = L.norm_apply(bp["ln_attn"], x, cfg)
     q, k, v = _project_qkv(bp["attn"], h, cfg, positions)       # (1, N, H*, D)
-    if policy is not None:
-        if mesh is None:
-            out, kp, vp, ksc, vsc = quantized_paged_update_attention(
-                q[0], k[0], v[0], kp, vp, ksc, vsc, wb, wo, row_tables,
-                lengths, policy=policy)
+    with jax.named_scope("paged_attention"):
+        if policy is not None:
+            if mesh is None:
+                out, kp, vp, ksc, vsc = quantized_paged_update_attention(
+                    q[0], k[0], v[0], kp, vp, ksc, vsc, wb, wo, row_tables,
+                    lengths, policy=policy)
+            else:
+                (out, kp, vp, ksc,
+                 vsc) = sharded_quantized_paged_update_attention(
+                    q[0], k[0], v[0], kp, vp, ksc, vsc, wb, wo, row_tables,
+                    lengths, policy=policy, mesh=mesh, axis="data")
+        elif mesh is None:
+            out, kp, vp = paged_update_attention(
+                q[0], k[0], v[0], kp, vp, wb, wo, row_tables, lengths)
         else:
-            out, kp, vp, ksc, vsc = sharded_quantized_paged_update_attention(
-                q[0], k[0], v[0], kp, vp, ksc, vsc, wb, wo, row_tables,
-                lengths, policy=policy, mesh=mesh, axis="data")
-    elif mesh is None:
-        out, kp, vp = paged_update_attention(
-            q[0], k[0], v[0], kp, vp, wb, wo, row_tables, lengths)
-    else:
-        out, kp, vp = sharded_paged_update_attention(
-            q[0], k[0], v[0], kp, vp, wb, wo, row_tables, lengths,
-            mesh=mesh, axis="data")
+            out, kp, vp = sharded_paged_update_attention(
+                q[0], k[0], v[0], kp, vp, wb, wo, row_tables, lengths,
+                mesh=mesh, axis="data")
     attn_out = L.dense_apply(bp["attn"]["wo"], out.reshape(1, N, -1), cfg)
     x = x + attn_out
     x = shard(x, "batch", "seq", "embed")
@@ -654,101 +656,113 @@ class ContinuousEngine:
         With preemption enabled (``serve.slo``), the step first lets the
         scheduler evict lower-priority victims for urgent arrivals that
         could not otherwise be admitted — eviction and re-admission both
-        happen here, at step granularity, never mid-forward."""
-        self.scheduler.maybe_preempt(clock_ms)
-        # deadline-aware shedding (slo.shed): provably-late requests are
-        # finished with Status.SHED at the door, surfaced alongside the
-        # step's completions so run()/callers see them resolve
-        shed = self.scheduler.shed_unmeetable(clock_ms)
-        admitted = self.scheduler.admit(clock_ms)
-        if self.mode == "recurrent":
-            for st in admitted:
-                self._state = self._reset_fn(self._state, jnp.int32(st.slot))
-        if not self.scheduler.running:
-            return shed
-        if self.mode == "paged":
-            # speculate only in decode-only steps: mid-prefill, the mixed
-            # step makes prompt progress and decode slots emit one token
-            if self.spec is not None and self.scheduler.prefilling is None:
-                finished = self._verify_host_step(clock_ms)
+        happen here, at step granularity, never mid-forward.
+
+        The whole step is one ``engine_step`` span; the paged steps nest
+        the phase spans ``engine_schedule`` → ``engine_rows`` →
+        ``engine_launch`` → ``engine_wait`` → ``engine_samples`` →
+        ``engine_publish`` inside it (``docs/observability.md``)."""
+        tr = self.obs.tracer
+        with tr.span("engine_step") as sp:
+            with tr.span("engine_schedule"):
+                self.scheduler.maybe_preempt(clock_ms)
+                # deadline-aware shedding (slo.shed): provably-late
+                # requests are finished with Status.SHED at the door,
+                # surfaced alongside the step's completions so
+                # run()/callers see them resolve
+                shed = self.scheduler.shed_unmeetable(clock_ms)
+                admitted = self.scheduler.admit(clock_ms)
+            if self.mode == "recurrent":
+                for st in admitted:
+                    self._state = self._reset_fn(self._state,
+                                                 jnp.int32(st.slot))
+            if not self.scheduler.running:
+                sp.args.update(kind="idle", step=self.steps, rows=0,
+                               live_rows=0)
+                return shed
+            if self.mode == "paged":
+                # speculate only in decode-only steps: mid-prefill, the
+                # mixed step makes prompt progress and decode slots emit
+                # one token
+                if self.spec is not None and self.scheduler.prefilling is None:
+                    finished = self._verify_host_step(clock_ms, sp)
+                else:
+                    finished = self._paged_host_step(clock_ms, sp)
             else:
-                finished = self._paged_host_step(clock_ms)
-        else:
-            finished = self._recurrent_host_step(clock_ms)
-        self.steps += 1
-        if self.check_invariants:
-            self.scheduler.check_conservation()
+                finished = self._recurrent_host_step(clock_ms, sp)
+            self.steps += 1
+            if self.check_invariants:
+                self.scheduler.check_conservation()
         return shed + finished
 
-    def _paged_host_step(self, clock_ms: float) -> List[RequestState]:
+    def _paged_host_step(self, clock_ms: float, sp) -> List[RequestState]:
         serve, cache, sched = self.serve, self.cache, self.scheduler
+        tr = self.obs.tracer
         S = serve.max_slots
         pre = sched.prefilling
         chunk = 0
         stream = target = None
-        if pre is not None:
-            # the prefill stream is the *confirmed* token sequence, not
-            # just the prompt: a restored preempted request re-ingests
-            # (or re-bound) prompt + fed-back samples up to the exact
-            # position it was evicted at — identical K/V, identical
-            # routing, by construction
-            stream = pre.confirmed_tokens
-            target = pre.prefill_target
-            chunk = min(serve.prefill_chunk, target - pre.prefill_pos)
-        # Shard-major row layout over the mesh's data axis (D = 1 reduces
-        # to the original [S decode rows] + [chunk rows]): shard d owns
-        # rows [d * per, (d+1) * per) — its own slots' decode rows first,
-        # then chunk rows, which live on (and are masked on all but) the
-        # shard of the prefilling slot.  shard_map then splits the row
-        # batch along the data axis with no data movement.
-        D = self.data_shards
-        spd = S // D
-        per = spd + (serve.prefill_chunk if pre is not None else 0)
-        N = D * per
+        with tr.span("engine_rows"):
+            if pre is not None:
+                # the prefill stream is the *confirmed* token sequence,
+                # not just the prompt: a restored preempted request
+                # re-ingests (or re-bound) prompt + fed-back samples up
+                # to the exact position it was evicted at — identical
+                # K/V, identical routing, by construction
+                stream = pre.confirmed_tokens
+                target = pre.prefill_target
+                chunk = min(serve.prefill_chunk, target - pre.prefill_pos)
+            # Shard-major row layout over the mesh's data axis (D = 1
+            # reduces to the original [S decode rows] + [chunk rows]):
+            # shard d owns rows [d * per, (d+1) * per) — its own slots'
+            # decode rows first, then chunk rows, which live on (and are
+            # masked on all but) the shard of the prefilling slot.
+            # shard_map then splits the row batch along the data axis
+            # with no data movement.
+            D = self.data_shards
+            spd = S // D
+            per = spd + (serve.prefill_chunk if pre is not None else 0)
+            N = D * per
 
-        def row_of(slot: int) -> int:
-            return (slot // spd) * per + slot % spd
+            def row_of(slot: int) -> int:
+                return (slot // spd) * per + slot % spd
 
-        b = _row_buffers(N, serve.blocks_per_slot, cache.garbage_block)
-        sample_rows: List[Tuple[int, RequestState]] = []
+            b = _row_buffers(N, serve.blocks_per_slot, cache.garbage_block)
+            sample_rows: List[Tuple[int, RequestState]] = []
 
-        for slot, st in sched.running.items():
-            if st.status is not Status.DECODE:
-                continue
-            pos = st.context_len
-            cache.ensure_capacity(slot, pos + 1)
-            _fill_row(b, cache, row_of(slot), slot, st.last_token, pos)
-            sample_rows.append((row_of(slot), st))
+            for slot, st in sched.running.items():
+                if st.status is not Status.DECODE:
+                    continue
+                pos = st.context_len
+                cache.ensure_capacity(slot, pos + 1)
+                _fill_row(b, cache, row_of(slot), slot, st.last_token, pos)
+                sample_rows.append((row_of(slot), st))
 
-        if pre is not None:
-            cache.ensure_capacity(pre.slot, pre.prefill_pos + chunk)
-            base = (pre.slot // spd) * per + spd
-            for j in range(chunk):
-                row, p = base + j, pre.prefill_pos + j
-                _fill_row(b, cache, row, pre.slot, stream[p], p)
-                # sample off the last *prompt* row only on first ingest:
-                # a resume past it already holds that sample in generated
-                if p == pre.request.prompt_len - 1 and not pre.generated:
-                    sample_rows.append((row, pre))
+            if pre is not None:
+                cache.ensure_capacity(pre.slot, pre.prefill_pos + chunk)
+                base = (pre.slot // spd) * per + spd
+                for j in range(chunk):
+                    row, p = base + j, pre.prefill_pos + j
+                    _fill_row(b, cache, row, pre.slot, stream[p], p)
+                    # sample off the last *prompt* row only on first
+                    # ingest: a resume past it already holds that sample
+                    # in generated
+                    if p == pre.request.prompt_len - 1 and not pre.generated:
+                        sample_rows.append((row, pre))
 
-        kind = "mixed" if pre is not None else "decode"
-        live = len(sample_rows) + (chunk if pre is not None else 0)
-        if pre is not None and any(st is pre for _, st in sample_rows):
-            live -= 1       # pre's sample row is one of its chunk rows
-        tr = self.obs.tracer
-        with tr.span("engine_step", kind=kind, step=self.steps,
-                     rows=N, live_rows=live):
-            if self.mesh is not None and tr.enabled:
-                # per-shard child spans: each shard's slice of the row
-                # batch (rows [d*per, (d+1)*per)), with its own live-row
-                # census — the mesh analogue of the step-level args
-                for d in range(D):
-                    sl = int(np.count_nonzero(
-                        b["lengths"][d * per:(d + 1) * per]))
-                    with tr.span("engine_step_shard", kind=kind,
-                                 step=self.steps, shard=d, rows=per,
-                                 live_rows=sl):
-                        pass
+            kind = "mixed" if pre is not None else "decode"
+            live = len(sample_rows) + (chunk if pre is not None else 0)
+            if pre is not None and any(st is pre for _, st in sample_rows):
+                live -= 1       # pre's sample row is one of its chunk rows
+            sp.args.update(kind=kind, step=self.steps, rows=N,
+                           live_rows=live)
+            if self.mesh is not None:
+                # each shard's slice of the row batch (rows [d*per,
+                # (d+1)*per)) with its own live-row census, shard 0 first
+                sp.args["live_rows_by_shard"] = " ".join(
+                    str(np.count_nonzero(b["lengths"][d * per:(d + 1) * per]))
+                    for d in range(D))
+        with tr.span("engine_launch"):
             (next_tok, k_pools, v_pools, k_scales, v_scales,
              telem) = self._step_fn(
                 self.params, cache.k_pool, cache.v_pool, b["tokens"],
@@ -756,18 +770,20 @@ class ContinuousEngine:
                 b["wb"], b["wo"], b["slots"], self._key,
                 cache.k_scales, cache.v_scales)
             cache.update_pools(k_pools, v_pools, k_scales, v_scales)
-        self._moe_accum(telem, N)
-
-        if pre is not None:
-            pre.prefill_pos += chunk
-            if pre.prefill_pos == target:
-                pre.status = Status.DECODE
-                self.obs.request_phase(pre.request.uid, "decode",
-                                       slot=pre.slot)
-        finished = self._collect_samples(np.asarray(next_tok), sample_rows,
-                                         clock_ms)
-        self._commit_running()
-        self._obs_step(kind, live, N)
+            self._moe_accum(telem, N)
+        with tr.span("engine_wait"):
+            next_tok = np.asarray(next_tok)
+        with tr.span("engine_samples"):
+            if pre is not None:
+                pre.prefill_pos += chunk
+                if pre.prefill_pos == target:
+                    pre.status = Status.DECODE
+                    self.obs.request_phase(pre.request.uid, "decode",
+                                           slot=pre.slot)
+            finished = self._collect_samples(next_tok, sample_rows, clock_ms)
+            self._commit_running()
+        with tr.span("engine_publish"):
+            self._obs_step(kind, live, N)
         return finished
 
     def _commit_running(self) -> None:
@@ -796,97 +812,109 @@ class ContinuousEngine:
         return np.random.default_rng(
             np.random.SeedSequence(entropy=[self.seed, slot, position]))
 
-    def _verify_host_step(self, clock_ms: float) -> List[RequestState]:
+    def _verify_host_step(self, clock_ms: float, sp) -> List[RequestState]:
         serve, cache, sched = self.serve, self.cache, self.scheduler
+        tr = self.obs.tracer
         S, gamma = serve.max_slots, self.spec.gamma
         W = gamma + 1
         N = S * W
 
-        items: List[DraftItem] = []
-        for slot, st in sorted(sched.running.items()):
-            # remaining >= 1 in DECODE (a drained budget evicts); a draft
-            # never needs to run past it, and clamping keeps every draft
-            # KV write below total_len — inside the admission reservation
-            remaining = st.request.max_new_tokens - len(st.generated)
-            context = np.concatenate(
-                [st.request.prompt,
-                 np.asarray(st.generated, np.int32)]).astype(np.int32)
-            items.append(DraftItem(slot=slot, context=context,
-                                   max_tokens=min(gamma, remaining)))
-        proposals = self.drafter.propose(items)
-        drafts = [np.asarray(d, np.int32).reshape(-1)[:it.max_tokens]
-                  for it, d in zip(items, proposals)]
-        if all(d.size == 0 for d in drafts):
+        with tr.span("engine_rows"):
+            items: List[DraftItem] = []
+            for slot, st in sorted(sched.running.items()):
+                # remaining >= 1 in DECODE (a drained budget evicts); a
+                # draft never needs to run past it, and clamping keeps
+                # every draft KV write below total_len — inside the
+                # admission reservation
+                remaining = st.request.max_new_tokens - len(st.generated)
+                context = np.concatenate(
+                    [st.request.prompt,
+                     np.asarray(st.generated, np.int32)]).astype(np.int32)
+                items.append(DraftItem(slot=slot, context=context,
+                                       max_tokens=min(gamma, remaining)))
+            proposals = self.drafter.propose(items)
+            drafts = [np.asarray(d, np.int32).reshape(-1)[:it.max_tokens]
+                      for it, d in zip(items, proposals)]
+            nothing_drafted = all(d.size == 0 for d in drafts)
+            if not nothing_drafted:
+                b = _row_buffers(N, serve.blocks_per_slot,
+                                 cache.garbage_block)
+                per_slot: Dict[int, Tuple[RequestState, np.ndarray, int]] = {}
+                for it, d in zip(items, drafts):
+                    slot = it.slot
+                    st = sched.running[slot]
+                    g = int(d.size)
+                    c = st.context_len
+                    cache.ensure_capacity(slot, c + g + 1)
+                    row_toks = [st.last_token, *d.tolist()]
+                    for j in range(g + 1):
+                        _fill_row(b, cache, slot * W + j, slot, row_toks[j],
+                                  c + j)
+                    per_slot[slot] = (st, d, c)
+                live = sum(int(d.size) + 1 for _, d, _ in per_slot.values())
+                sp.args.update(kind="verify", step=self.steps, rows=N,
+                               live_rows=live)
+        if nothing_drafted:
             # nothing to verify anywhere: an ordinary decode step costs
             # 1/(gamma+1) the rows for the same one token per slot (the
             # decode-only shape is already in the compiled census)
-            return self._paged_host_step(clock_ms)
+            return self._paged_host_step(clock_ms, sp)
 
-        b = _row_buffers(N, serve.blocks_per_slot, cache.garbage_block)
-        per_slot: Dict[int, Tuple[RequestState, np.ndarray, int]] = {}
-        for it, d in zip(items, drafts):
-            slot = it.slot
-            st = sched.running[slot]
-            g = int(d.size)
-            c = st.context_len
-            cache.ensure_capacity(slot, c + g + 1)
-            row_toks = [st.last_token, *d.tolist()]
-            for j in range(g + 1):
-                _fill_row(b, cache, slot * W + j, slot, row_toks[j], c + j)
-            per_slot[slot] = (st, d, c)
-
-        live = sum(int(d.size) + 1 for _, d, _ in per_slot.values())
-        with self.obs.tracer.span("engine_step", kind="verify",
-                                  step=self.steps, rows=N, live_rows=live):
+        with tr.span("engine_launch"):
             (scores, k_pools, v_pools, k_scales, v_scales,
              telem) = self._verify_fn(
                 self.params, cache.k_pool, cache.v_pool, b["tokens"],
                 b["ctx_ids"], b["positions"], b["lengths"], b["row_tables"],
                 b["wb"], b["wo"], cache.k_scales, cache.v_scales)
             cache.update_pools(k_pools, v_pools, k_scales, v_scales)
-        self._moe_accum(telem, N)
-        scores = np.asarray(scores)     # (N,) argmax ids | (N, V) logits
+            self._moe_accum(telem, N)
+        with tr.span("engine_wait"):
+            scores = np.asarray(scores)  # (N,) argmax ids | (N, V) logits
 
+        m = self.obs.metrics
         finished = []
-        for slot, (st, d, c) in per_slot.items():
-            g = int(d.size)
-            rows = scores[slot * W: slot * W + g + 1]
-            if self.temperature <= 0.0:
-                emitted, n_acc = accept_greedy_ids(d, rows)
-            else:
-                emitted, n_acc = accept_rejection(
-                    d, rows, self.temperature,
-                    lambda j, slot=slot, c=c: self._host_rng(slot, c + j))
-            remaining = st.request.max_new_tokens - len(st.generated)
-            emitted = emitted[:remaining]
-            eos = st.request.eos_id
-            if eos is not None and eos in emitted:
-                emitted = emitted[:emitted.index(eos) + 1]
-            assert emitted, "verify step must emit at least the bonus token"
-            m = self.obs.metrics
-            m.counter("spec_proposed_total").inc(g)
-            # accepted = draft tokens actually *used*: the EOS/budget cut
-            # can discard accepted drafts, which must not inflate the rate
-            m.counter("spec_accepted_total").inc(min(len(emitted), n_acc))
-            st.generated.extend(int(t) for t in emitted)
-            if st.first_token_ms is None:
-                st.first_token_ms = clock_ms
-            m.counter("spec_emitted_total").inc(len(emitted))
-            if st.done():
-                self.scheduler.finish(st, clock_ms)
-                finished.append(st)
-            else:
-                # rollback: positions [0, c + len(emitted)) stay written
-                # (row j wrote draft token j at position c + j, which for
-                # every kept row IS the fed-back token); rejected rows
-                # beyond rewind, their spill blocks return to the pool
-                cache.truncate_slot(slot, c + len(emitted))
-        self.obs.metrics.counter("spec_verify_steps_total").inc()
-        self._commit_running()
-        self._obs_step("verify", live, N)
+        with tr.span("engine_samples"):
+            for slot, (st, d, c) in per_slot.items():
+                g = int(d.size)
+                rows = scores[slot * W: slot * W + g + 1]
+                if self.temperature <= 0.0:
+                    emitted, n_acc = accept_greedy_ids(d, rows)
+                else:
+                    emitted, n_acc = accept_rejection(
+                        d, rows, self.temperature,
+                        lambda j, slot=slot, c=c: self._host_rng(slot, c + j))
+                remaining = st.request.max_new_tokens - len(st.generated)
+                emitted = emitted[:remaining]
+                eos = st.request.eos_id
+                if eos is not None and eos in emitted:
+                    emitted = emitted[:emitted.index(eos) + 1]
+                assert emitted, "verify step must emit at least the bonus token"
+                m.counter("spec_proposed_total").inc(g)
+                # accepted = draft tokens actually *used*: the EOS/budget
+                # cut can discard accepted drafts, which must not inflate
+                # the rate
+                m.counter("spec_accepted_total").inc(min(len(emitted), n_acc))
+                st.generated.extend(int(t) for t in emitted)
+                if st.first_token_ms is None:
+                    st.first_token_ms = clock_ms
+                m.counter("spec_emitted_total").inc(len(emitted))
+                if st.done():
+                    self.scheduler.finish(st, clock_ms)
+                    finished.append(st)
+                else:
+                    # rollback: positions [0, c + len(emitted)) stay
+                    # written (row j wrote draft token j at position
+                    # c + j, which for every kept row IS the fed-back
+                    # token); rejected rows beyond rewind, their spill
+                    # blocks return to the pool
+                    cache.truncate_slot(slot, c + len(emitted))
+            self._commit_running()
+        with tr.span("engine_publish"):
+            m.counter("spec_verify_steps_total").inc()
+            self._obs_step("verify", live, N)
         return finished
 
-    def _recurrent_host_step(self, clock_ms: float) -> List[RequestState]:
+    def _recurrent_host_step(self, clock_ms: float, sp) -> List[RequestState]:
         S = self.serve.max_slots
         tokens = np.zeros((S, 1), np.int32)
         positions = np.zeros(S, np.int32)
@@ -905,10 +933,10 @@ class ContinuousEngine:
                 sample_rows.append((slot, st))
 
         live = len(self.scheduler.running)
-        with self.obs.tracer.span("engine_step", kind="decode",
-                                  step=self.steps, rows=S, live_rows=live):
-            next_tok, self._state = self._step_fn(self.params, self._state,
-                                                  tokens, positions, self._key)
+        sp.args.update(kind="decode", step=self.steps, rows=S,
+                       live_rows=live)
+        next_tok, self._state = self._step_fn(self.params, self._state,
+                                              tokens, positions, self._key)
         for st in prefill_advanced:
             st.prefill_pos += 1
             if st.prefill_pos == st.request.prompt_len:

@@ -537,9 +537,9 @@ def test_kv_pool_bytes_metric_shrinks_under_int8(tmp_path):
 
 
 def test_mesh_trace_validates_with_require(tmp_path):
-    """A 1x1-mesh serve run emits per-shard engine_step_shard spans
-    inside each engine_step span; the written Chrome trace validates,
-    and the metrics file validates with --require for the new gauge."""
+    """A 1x1-mesh serve run carries each shard's live-row census in its
+    engine_step spans' args; the written Chrome trace validates, and
+    the metrics file validates with --require for the new gauge."""
     from repro.obs import Observability
     from repro.obs.validate import (
         validate_chrome_trace,
@@ -552,10 +552,11 @@ def test_mesh_trace_validates_with_require(tmp_path):
     _, _, eng = _trace(cfg, params, kv_quant="int8", mesh=TRIVIAL_MESH,
                        obs=obs)
     spans = [e for e in obs.tracer.events()
-             if e.get("name") == "engine_step_shard"]
-    assert spans, "mesh path emitted no per-shard spans"
-    assert all(e["args"]["shard"] == 0 for e in spans)
-    assert all("live_rows" in e["args"] for e in spans)
+             if e.get("name") == "engine_step" and e["args"]["rows"]]
+    assert spans, "mesh path emitted no engine_step spans"
+    # one data shard: its census is the whole step's
+    assert all(e["args"]["live_rows_by_shard"] == str(e["args"]["live_rows"])
+               for e in spans)
     trace_path = str(tmp_path / "trace.json")
     metrics_path = str(tmp_path / "metrics.jsonl")
     obs.tracer.write_chrome_trace(trace_path)

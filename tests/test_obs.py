@@ -150,7 +150,7 @@ def test_metrics_jsonl_row_is_schema_valid(tmp_path):
 def test_tracer_disabled_is_noop():
     tr = SpanTracer(enabled=False)
     with tr.span("work") as sp:
-        assert sp is None
+        sp.args["rows"] = 8          # late args are accepted, ring or not
     tr.begin("request", 1, "queued")
     tr.instant("preempt")
     assert tr.events() == []
@@ -365,3 +365,102 @@ def test_queue_and_latency_histograms_populate():
     assert snap["request_queue_ms_count"] == 3
     assert snap["request_latency_ms_count"] == 3
     assert snap["request_latency_ms_sum"] >= snap["request_queue_ms_sum"]
+
+
+# ---------------------------------------------------------------------------
+# Profiler bridge: engine spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+PHASES = ["engine_schedule", "engine_rows", "engine_launch", "engine_wait",
+          "engine_samples", "engine_publish"]
+
+
+def _host_events(profile_dir, prefix):
+    """(name, start_ns, end_ns, stats) of the host events of the trace in
+    ``profile_dir`` whose name starts with ``prefix``."""
+    from jax.profiler import ProfileData
+
+    (path,) = profile_dir.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+def _profiled_run(tmp_path, obs):
+    cfg = tiny_cfg(num_layers=1)
+    serve = ServeConfig(max_slots=2, kv_block_size=8, prefill_chunk=4,
+                        max_len=32)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng, out, _ = _run(cfg, serve, obs=obs)
+    finally:
+        jax.profiler.stop_trace()
+    return eng, out, _host_events(tmp_path, "engine_")
+
+
+def test_engine_spans_reach_the_profiler(tmp_path):
+    """Each serving step is an engine_step annotation holding the six
+    phase spans in order, its args (and the ring's) as profiler stats."""
+    eng, _, evs = _profiled_run(tmp_path, Observability(tracing=True))
+    steps = [e for e in evs
+             if e[0] == "engine_step" and e[3]["kind"] != "idle"]
+    assert len(steps) == eng.steps > 0
+    ring = [e["args"] for e in eng.obs.tracer.events()
+            if e["name"] == "engine_step" and e["args"]["kind"] != "idle"]
+    assert [st for _, _, _, st in steps] == ring
+    for _, s, e, stats in steps:
+        assert stats["kind"] in ("mixed", "decode")
+        assert 0 < stats["live_rows"] <= stats["rows"]
+        inner = sorted((ps, pe, n) for n, ps, pe, _ in evs
+                       if n != "engine_step" and s <= ps and pe <= e)
+        assert [n for _, _, n in inner] == PHASES
+        assert all(a[1] <= b[0] for a, b in zip(inner, inner[1:]))
+
+
+def test_engine_spans_reach_the_profiler_with_the_ring_off(tmp_path):
+    eng, _, evs = _profiled_run(tmp_path, Observability(tracing=False))
+    assert eng.obs.tracer.events() == []
+    steps = [e for e in evs
+             if e[0] == "engine_step" and e[3]["kind"] != "idle"]
+    assert len(steps) == eng.steps > 0
+    for name in PHASES:
+        assert sum(n == name for n, _, _, _ in evs) == eng.steps
+
+
+def test_profiler_on_off_token_identity(tmp_path):
+    """A profiler session around the engine must not change tokens."""
+    cfg = tiny_cfg(num_layers=1)
+    serve = ServeConfig(max_slots=2, kv_block_size=8, prefill_chunk=4,
+                        max_len=32)
+    _, out_off, _ = _run(cfg, serve)
+    _, out_on, evs = _profiled_run(tmp_path, None)
+    assert evs and out_on == out_off
+
+
+def test_train_rate_is_tokens_over_the_log_interval(tmp_path, monkeypatch):
+    """A log step's token rate covers every step since the previous log
+    step, over the clock read after each log step's device_get; the
+    train_step spans reach the profiler."""
+    import itertools
+
+    from repro.launch import train
+
+    ticks = itertools.count()                 # one second a clock read
+    monkeypatch.setattr(train, "_clock", lambda: float(next(ticks)))
+    monkeypatch.setattr(train, "enable_compile_cache", lambda: None)
+    metrics, prof = tmp_path / "m.jsonl", tmp_path / "prof"
+    batch, seq = 2, 16
+    train.main(["--arch", "m6-base", "--smoke", "--steps", "5",
+                "--batch", str(batch), "--seq", str(seq), "--log-every", "2",
+                "--metrics-out", str(metrics), "--profile-dir", str(prof)])
+    rows = [json.loads(line) for line in open(metrics)]
+    got = {r["step"]: r["metrics"]["train_tokens_per_s"]
+           for r in rows if "step" in r}
+    tokens = batch * seq
+    # logged at steps 0, 2, 4: 1, 2 and 2 steps in 1 s each
+    assert got == {0: 1.0 * tokens, 2: 2.0 * tokens, 4: 2.0 * tokens}
+    spans = _host_events(prof, "train_step")
+    assert sorted(st["step"] for _, _, _, st in spans) == list(range(5))
