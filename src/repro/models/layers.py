@@ -4,7 +4,10 @@ Every module is a pair of pure functions:
   ``<name>_specs(cfg, ...) -> ParamSpec tree``
   ``<name>_apply(params, x, ...) -> array``
 Mixed precision: parameters are stored in ``cfg.param_dtype`` and cast to
-``cfg.dtype`` at use; norms and routers compute in float32.
+``cfg.dtype`` at use; norms and routers compute in float32.  The paged
+serving engine (``repro.serving.continuous``) holds the ``param_dtype``
+leaves already in ``cfg.dtype``, cast once when it is built, so there the
+cast at use is a no-op.
 """
 from __future__ import annotations
 

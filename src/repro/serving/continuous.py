@@ -90,6 +90,7 @@ from repro.models import layers as L
 from repro.models.attention import _project_qkv
 from repro.models.registry import get_family
 from repro.models.transformer import _is_moe_layer
+from repro.nn import ParamSpec
 from repro.obs import Observability
 from repro.serving.kv_cache import PagedKVCache, ShardedPagedKVCache
 from repro.serving.request import Request, RequestState, Status
@@ -297,6 +298,45 @@ def _sample_rows(logits, slots, positions, *, temperature: float, key):
 
 
 # ---------------------------------------------------------------------------
+# Serving weights
+# ---------------------------------------------------------------------------
+
+def _stored_in_param_dtype(specs_fn, cfg: ModelConfig):
+    """Bool tree over the family's parameters: True where the spec's dtype
+    follows ``cfg.param_dtype`` (kernels, expert stacks, embedding tables,
+    learned positions), False where it is fixed (float32 norms, biases,
+    routers).  Read off the specs at two probe dtypes, where exactly those
+    leaves differ — with ``param_dtype`` float32 the dtypes alone cannot
+    tell a kernel from a norm scale."""
+    lo = specs_fn(cfg.replace(param_dtype="bfloat16"))
+    hi = specs_fn(cfg.replace(param_dtype="float32"))
+    return jax.tree_util.tree_map(lambda a, b: a.dtype != b.dtype, lo, hi,
+                                  is_leaf=lambda s: isinstance(s, ParamSpec))
+
+
+def serving_params(specs_fn, cfg: ModelConfig, params):
+    """``params`` with every ``param_dtype`` leaf cast to ``cfg.dtype``, in
+    one jitted call on the device, each leaf keeping its sharding; every
+    other leaf is passed through.  The layers cast exactly these leaves
+    at use, so the step computes with the same values and its casts are
+    no-ops that XLA drops.  ``param_dtype == dtype`` returns ``params``."""
+    dt = jnp.dtype(cfg.dtype)
+    if jnp.dtype(cfg.param_dtype) == dt:
+        return params
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    flags = treedef.flatten_up_to(_stored_in_param_dtype(specs_fn, cfg))
+    idx = [i for i, f in enumerate(flags) if f]
+    src = [leaves[i] for i in idx]
+    # an elementwise cast keeps each input's sharding, and its
+    # commitment: explicit out_shardings would commit an uncommitted tree,
+    # and the step would compile again once its pools come back committed
+    cast = jax.jit(lambda xs: [x.astype(dt) for x in xs])(src)
+    for i, x in zip(idx, cast):
+        leaves[i] = x
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+# ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
 
@@ -337,7 +377,10 @@ class ContinuousEngine:
                 f"batch-wide length; encdec needs per-request encoder memory)")
         self.cfg = cfg
         self.fam = get_family(cfg)
-        self.params = params
+        # paged steps read the weights already in the compute dtype: cast
+        # once here, not on every step (recurrent mode keeps the caller's)
+        self.params = (serving_params(self.fam.specs, cfg, params)
+                       if self.mode == "paged" else params)
         self.serve = serve
         self.temperature = float(temperature)
         self.rules = rules
@@ -352,6 +395,11 @@ class ContinuousEngine:
         # measure logit divergence across kv_quant policies)
         self._logit_tap = logit_tap
         self.obs = obs if obs is not None else Observability()
+        held: Dict[str, int] = {}           # weight bytes by dtype
+        for x in jax.tree_util.tree_leaves(self.params):
+            held[x.dtype.name] = held.get(x.dtype.name, 0) + x.nbytes
+        for dtype, nbytes in held.items():
+            self.obs.metrics.gauge("engine_weight_bytes", dtype=dtype).set(nbytes)
         self._moe_acc = None        # device-side telemetry accumulator
         self._moe_rows = 0          # host row count backing the entropy mean
         self._seen_variants = 0     # compiled-variant census (recompile det.)

@@ -290,6 +290,33 @@ def test_recompile_detector_fires_on_excess_variants():
                if e["ph"] == "i")
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_engine_weight_bytes_gauge(dtype):
+    """``engine_weight_bytes{dtype}`` sums to the bytes of the tree the
+    engine holds; stored in float32 and served in bf16, its bf16 share is
+    exactly the expert stacks, dense kernels and embedding table."""
+    cfg = tiny_cfg(dtype=dtype, d_ff=96,
+                   moe=MoEConfig(num_experts=4, routing="topk", top_k=2,
+                                 impl="dropless", capacity_factor=None))
+    serve = ServeConfig(max_slots=2, kv_block_size=8, prefill_chunk=4,
+                        max_len=32)
+    eng = ContinuousEngine(cfg, build(cfg), serve)
+    m = eng.obs.metrics
+    leaves = jax.tree_util.tree_leaves_with_path(eng.params)
+    total = sum(x.size * x.dtype.itemsize for _, x in leaves)
+    assert sum(m.get("engine_weight_bytes", dtype=d)
+               for d in ("bfloat16", "float32")) == total
+    low = sum(x.size * 2 for p, x in leaves
+              if getattr(p[-1], "key", None) in
+              ("up", "gate", "down", "kernel", "table"))
+    if dtype == "bfloat16":
+        assert m.get("engine_weight_bytes", dtype="bfloat16") == low > 0
+        assert m.get("engine_weight_bytes", dtype="float32") == total - low
+    else:
+        assert m.get("engine_weight_bytes", dtype="float32") == total
+        assert m.get("engine_weight_bytes", dtype="bfloat16") == 0
+
+
 def test_moe_dropless_dropped_fraction_exact_zero():
     cfg = tiny_cfg(d_ff=96, num_layers=2,
                    moe=MoEConfig(num_experts=4, routing="topk", top_k=2,
