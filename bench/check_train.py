@@ -23,6 +23,8 @@ import importlib
 
 import numpy as np
 
+from bench import arch
+
 IGNORE_BELOW = 1e-3
 
 
@@ -63,41 +65,31 @@ def _adafactor(mc: dict):
     return init, update
 
 
-MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_up", "w_gate", "w_down", "embed",
-                  "unembed")
-
-
-def _parts(names) -> list:
-    """Leaves whose gradients are taken in one pass: each expert matrix
-    alone, then the rest together."""
-    big = [(n,) for n in ("w_up", "w_gate", "w_down") if n in names]
-    rest = tuple(n for n in names if (n,) not in big)
-    return big + [rest]
-
-
 def reference_readings(mc: dict, w: dict, batches: list, prec: str = "f32"):
     """Losses, first-gradient norms and the weights after three steps of
     the reference, per leaf by the benchmark's weight names.
 
-    The gradient is taken in passes of a few leaves each (``_parts``),
-    each pass's part kept on the host, so that the chip holds the
-    weights and one part of the gradient at a time; the full gradient
-    comes back for the global-norm clipping and the update.
-    ``prec="fp8"`` is the control: the gradient is taken at the weights
-    rounded to float8 (the optimizer keeps float32 weights, as the
-    program does), the float32 weights waiting on the host meanwhile."""
+    The gradient is taken in passes of a few leaves each (the form's
+    ``grad_parts``, ``bench/arch``), each pass's part kept on the host,
+    so that the chip holds the weights and one part of the gradient at a
+    time; the full gradient comes back for the global-norm clipping and
+    the update.  ``prec="fp8"`` is the control: the gradient is taken at
+    the weights rounded to float8 (the form's ``MATMUL_WEIGHTS``; the
+    optimizer keeps float32 weights, as the program does), the float32
+    weights waiting on the host meanwhile."""
     import jax
     import jax.numpy as jnp
 
     ref = importlib.import_module(f"bench.reference.{mc['reference']}")
+    form = arch.load(mc)
     inner = "fp8w" if prec == "fp8" else prec
     names = sorted(w)
-    parts = _parts(names)
+    parts = form.grad_parts(names)
     grad_fns = {
         part: jax.jit(jax.value_and_grad(
             lambda a, rest, b: ref.loss({**a, **rest}, mc, b, inner)))
         for part in parts}
-    rounded = jax.jit(lambda w: {k: ref.fp8(v) if k in MATMUL_WEIGHTS else v
+    rounded = jax.jit(lambda w: {k: ref.fp8(v) if k in form.MATMUL_WEIGHTS else v
                                  for k, v in w.items()})
     init, update = _adafactor(mc)
     step_fn = jax.jit(lambda w, g, v, t: {n: update(w[n], g[n], v[n], t)

@@ -1,12 +1,15 @@
 """Pre-training batches made on the device from the seed.
 
 Parameters: ``batch`` rows of ``text_len`` token ids each, plus the
-configuration's ``num_image_tokens`` image features per row (random, at
-the embedding's scale, in place of the image encoder's output).  Step
+features the configuration's form places before the text
+(``train_prefix``, ``bench/arch``: M6's image features), random at the
+embedding's scale, in place of the image encoder's output.  Step
 ``i`` gets its own rows: the key is folded with ``i``, so no two steps
 share a batch.
 """
 from __future__ import annotations
+
+from bench import arch
 
 DRIVER = "train"
 
@@ -17,7 +20,8 @@ def batch_fn(traffic: dict, mc: dict):
     import jax.numpy as jnp
 
     B, S = traffic["batch"], traffic["text_len"]
-    P, d, V = mc["num_image_tokens"], mc["hidden_size"], mc["vocab_size"]
+    P, d = arch.load(mc).train_prefix(mc)
+    V = mc["vocab_size"]
     std = mc["initializer_range"]
 
     def make(key, step):

@@ -2,8 +2,10 @@
 
 Everything the benchmark knows about the program's own API sits here:
 how a configuration file becomes the program's ``ModelConfig``, how an
-engine section becomes a ``ServeConfig``, and where each of the
-benchmark's weight arrays goes in the program's parameter tree.  The
+engine section becomes a ``ServeConfig``, and how the benchmark's weight
+arrays are placed in the program's parameter tree.  What differs by
+architectural form (the sizes a ``ModelConfig`` takes, where each weight
+goes) comes from the configuration's module under ``bench/arch``.  The
 reference (``bench/reference``) never imports this module.
 """
 from __future__ import annotations
@@ -11,7 +13,7 @@ from __future__ import annotations
 import os
 import sys
 
-from bench import weights
+from bench import arch
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -25,55 +27,19 @@ def import_program():
         sys.path.insert(0, src)
 
 
-def _norm_eps(mc: dict) -> float:
-    return mc.get("layer_norm_eps", mc.get("rms_norm_eps"))
-
-
 def model_config(mc: dict, mode: str):
     """The program's ``ModelConfig`` for configuration file ``mc`` run as
-    ``mode`` ("serve" or "train").  Every size comes from the file; the
-    program's registry supplies the family and layer kinds, which must
-    agree with what the file states, and its parameters must have the
-    shapes of the benchmark's weights."""
+    ``mode`` ("serve" or "train").  The program's registry supplies the
+    family and layer kinds; the configuration's form (``bench/arch``)
+    puts in every size from the file and checks the kinds against what
+    the file states; the program's parameters must have the shapes of
+    the benchmark's weights."""
     import_program()
     from repro.configs.registry import get_config
 
-    if mc["qk_norm"] not in (False, "per_head"):
-        raise SystemExit(f"bench: {mc['name']} states qk_norm="
-                         f"{mc['qk_norm']!r}; the program's QK-norm is per head")
-    cfg = get_config(mc["registry_id"])
-    cfg = cfg.replace(
-        num_layers=mc["num_hidden_layers"], d_model=mc["hidden_size"],
-        num_heads=mc["num_attention_heads"],
-        num_kv_heads=mc["num_key_value_heads"], head_dim=mc["head_dim"],
-        d_ff=mc["intermediate_size"], vocab_size=mc["vocab_size"],
-        max_seq_len=mc["max_position_embeddings"],
-        num_image_tokens=mc["num_image_tokens"], norm_eps=_norm_eps(mc),
-        rope_theta=mc.get("rope_theta", cfg.rope_theta),
-        tie_embeddings=mc["tie_word_embeddings"], qk_norm=bool(mc["qk_norm"]),
-        initializer_range=mc["initializer_range"], dtype=mc["dtype"],
-        param_dtype=mc["param_dtype"])
-    moe = dict(num_experts=mc["num_experts"], top_k=mc["num_experts_per_tok"],
-               normalize_gates=mc["norm_topk_prob"], routing="topk")
-    if "group_size" in mc:
-        moe["group_size"] = mc["group_size"]
-    if "capacity_factor" in mc:
-        moe["capacity_factor"] = mc["capacity_factor"]
-    run = mc.get(mode, {})
-    if "moe_impl" in run:
-        moe["impl"] = run["moe_impl"]
-    if "capacity_factor" in run:
-        moe["capacity_factor"] = run["capacity_factor"]
-    cfg = cfg.replace_moe(**moe)
-    act = {"gelu_tanh": "gelu", "silu": "swiglu"}[mc["hidden_act"]]
-    pos = {"learned": "learned", "rope": "rope"}[mc["position_embedding"]]
-    stated = {"ffn_activation": act, "norm": mc["norm"], "pos_embed": pos}
-    for key, want in stated.items():
-        if getattr(cfg, key) != want:
-            raise SystemExit(f"bench: {mc['name']} states {key}={want!r}, "
-                             f"the program's {mc['registry_id']} runs "
-                             f"{getattr(cfg, key)!r}")
-    if program_shapes(cfg) != weights.shapes(mc):
+    form = arch.load(mc)
+    cfg = form.model_config(mc, mode, get_config(mc["registry_id"]))
+    if program_shapes(cfg, form.LAYOUT) != form.shapes(mc):
         raise SystemExit("bench: the program's parameter shapes differ from "
                          "the benchmark's weights")
     return cfg
@@ -90,33 +56,9 @@ def serve_config(engine: dict):
                        num_blocks=engine.get("num_blocks"))
 
 
-# benchmark weight name -> path in the program's parameter tree
-_LAYOUT = {
-    "embed": ("embed", "table"),
-    "unembed": ("unembed", "table"),
-    "pos_embed": ("pos_embed",),
-    "final_scale": ("final_norm", "scale"),
-    "final_bias": ("final_norm", "bias"),
-    "ln1_scale": ("blocks", "ln_attn", "scale"),
-    "ln1_bias": ("blocks", "ln_attn", "bias"),
-    "ln2_scale": ("blocks", "ln_ffn", "scale"),
-    "ln2_bias": ("blocks", "ln_ffn", "bias"),
-    "wq": ("blocks", "attn", "wq", "kernel"),
-    "wk": ("blocks", "attn", "wk", "kernel"),
-    "wv": ("blocks", "attn", "wv", "kernel"),
-    "wo": ("blocks", "attn", "wo", "kernel"),
-    "q_norm": ("blocks", "attn", "q_norm"),
-    "k_norm": ("blocks", "attn", "k_norm"),
-    "router": ("blocks", "ffn", "router"),
-    "w_up": ("blocks", "ffn", "up"),
-    "w_gate": ("blocks", "ffn", "gate"),
-    "w_down": ("blocks", "ffn", "down"),
-}
-
-
-def program_shapes(cfg) -> dict:
+def program_shapes(cfg, layout: dict) -> dict:
     """{benchmark weight name: shape} of the program's parameter tree,
-    checked leaf for leaf against ``_LAYOUT``."""
+    checked leaf for leaf against ``layout`` (weight name -> path)."""
     import jax
 
     from repro.models.registry import get_family
@@ -125,7 +67,7 @@ def program_shapes(cfg) -> dict:
     tree = abstract(get_family(cfg).specs(cfg))
     flat = {tuple(getattr(k, "key", k) for k in path): leaf.shape
             for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
-    names = {v: k for k, v in _LAYOUT.items()}
+    names = {v: k for k, v in layout.items()}
     unknown = sorted("/".join(p) for p in flat if p not in names)
     if unknown:
         raise SystemExit(f"bench: program parameters the benchmark does not "
@@ -133,22 +75,23 @@ def program_shapes(cfg) -> dict:
     return {names[p]: tuple(s) for p, s in flat.items()}
 
 
-def program_params(arrays: dict) -> dict:
+def program_params(mc: dict, arrays: dict) -> dict:
     """The program's parameter tree holding the benchmark's arrays."""
+    layout = arch.load(mc).LAYOUT
     tree: dict = {}
     for name, arr in arrays.items():
         node = tree
-        path = _LAYOUT[name]
+        path = layout[name]
         for k in path[:-1]:
             node = node.setdefault(k, {})
         node[path[-1]] = arr
     return tree
 
 
-def leaf_names(tree) -> list:
+def leaf_names(mc: dict, tree) -> list:
     """Benchmark weight names in the program tree's leaf order."""
     import jax
 
-    names = {v: k for k, v in _LAYOUT.items()}
+    names = {v: k for k, v in arch.load(mc).LAYOUT.items()}
     return [names[tuple(getattr(k, "key", k) for k in path)]
             for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.counts import attention, model, moe_ffn
+from bench import arch
 
 
 def percentile(values, q: float):
@@ -28,8 +28,9 @@ def traced_steps(run, kind=None):
 
 
 def step_flops(mc: dict, c: dict) -> float:
-    rows = sum(model.row_flops(mc, length) for _, length in c["rows"])
-    return rows + c["sampled"] * model.unembed_flops(mc)
+    form = arch.load(mc)
+    rows = sum(form.row_flops(mc, length) for _, length in c["rows"])
+    return rows + c["sampled"] * form.unembed_flops(mc)
 
 
 def mfu(run, kind=None):
@@ -46,26 +47,17 @@ def mfu(run, kind=None):
 
 def roofline(run, kernel: str):
     """Least time the chip needs for the kernel's work, over its time in
-    the trace: Σ max(flops / peak, bytes / bandwidth) over Σ kernel time."""
+    the trace: Σ max(flops / peak, bytes / bandwidth) over Σ kernel time.
+    The work of each layer's call is counted by the configuration's form
+    (``kernel_work``, ``bench/arch``)."""
     mc, peak = run["mc"], run["peak"]
-    L = mc["num_hidden_layers"]
     least, spent = 0.0, 0.0
     for c, t in traced_steps(run):
         if not t["kernel_calls"].get(kernel):
             continue
-        if kernel == "paged_attn":
-            f, b = attention.paged_attention(
-                c["rows"], mc["num_attention_heads"],
-                mc["num_key_value_heads"], mc["head_dim"])
-            per_layer = [(f, b)] * L
-        else:
-            if c["experts_hit"] is None:
-                return None
-            k = mc["num_experts_per_tok"]
-            per_layer = [moe_ffn.ragged_ffn(
-                len(c["rows"]) * k, hit, mc["hidden_size"],
-                mc["intermediate_size"], model.n_mats(mc))
-                for hit in c["experts_hit"]]
+        per_layer = arch.load(mc).kernel_work(kernel, mc, c)
+        if per_layer is None:
+            return None
         least += sum(max(f / peak["bf16_flops"], b / peak["hbm_bytes_per_s"])
                      for f, b in per_layer)
         spent += t["kernel_s"][kernel]

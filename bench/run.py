@@ -3,13 +3,15 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Looks the cell up in ``BENCHMARK.json`` and loads, by name, the cell's
-configuration (``bench/configs``), its traffic mix (``bench/traffic``),
-the traffic's generator (``bench/generators/<kind>.py``), the cell's
-correctness limits (``bench/limits/<cell>.json``) and one reader per
-metric (``bench/metrics/<metric>.py``, or for ``<quantity>.<cell
-group>`` such as ``device_idle.chat`` the quantity's own
-``bench/metrics/<quantity>.py``).  A new cell, mix, configuration or
-metric is new files and entries, never an edit here.
+configuration (``bench/configs``), the module of the architectural form
+whose plain reference the configuration names (``bench/arch``), its traffic
+mix (``bench/traffic``), the traffic's generator
+(``bench/generators/<kind>.py``), the cell's correctness limits
+(``bench/limits/<cell>.json``) and one reader per metric
+(``bench/metrics/<metric>.py``, or for ``<quantity>.<cell group>`` such
+as ``device_idle.chat`` the quantity's own
+``bench/metrics/<quantity>.py``).  A new cell, mix, configuration, form
+or metric is new files and entries, never an edit here.
 
 Runs only on a TPU whose kind is in ``bench/peaks.py``: with no
 accelerator, too few chips or an unknown kind it exits non-zero and
@@ -37,6 +39,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+from bench import arch  # noqa: E402
 
 
 def process_start_perf() -> float:
@@ -74,6 +78,7 @@ def load_cell(name: str):
     cell = cells[name]
     configs = {c["name"]: c for c in manifest["configs"]}
     mc = load_json(ROOT, configs[cell["config"]]["file"])
+    arch.load(mc)
     traffic = load_json(BENCH, "traffic", cell["traffic"] + ".json")
     limits = load_json(BENCH, "limits", name + ".json")
     return manifest, cell, mc, traffic, limits

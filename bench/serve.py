@@ -32,7 +32,7 @@ def build(mc: dict, traffic: dict, seed: int):
     from repro.serving.continuous import ContinuousEngine
 
     cfg = program.model_config(mc, "serve")
-    params = program.program_params(weights.make(mc, seed))
+    params = program.program_params(mc, weights.make(mc, seed))
     jax.block_until_ready(params)
     return ContinuousEngine(cfg, params, program.serve_config(traffic["engine"]))
 

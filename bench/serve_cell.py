@@ -4,11 +4,10 @@ from __future__ import annotations
 import gc
 import sys
 
-from bench import check_serve, seeds, serve, weights
+from bench import arch, check_serve, seeds, serve, weights
 from bench.trace import load_events, reduce
 
 STEP_PROGRAM = "step_fn"
-KERNELS = {"paged_attn": "paged_decode_attention", "moe_ffn": "ragged_ffn"}
 
 
 def run(cell: str, seed: int, seconds: float, mc: dict, traffic: dict,
@@ -29,7 +28,7 @@ def run(cell: str, seed: int, seconds: float, mc: dict, traffic: dict,
     run_rec = {"serve": rec, "setup_s": setup_s, "census": rec["census"]}
     device = {}
     if trace_dir:
-        tr = reduce(*load_events(trace_dir), STEP_PROGRAM, KERNELS)
+        tr = reduce(*load_events(trace_dir), STEP_PROGRAM, arch.load(mc).KERNELS)
         run_rec["trace"] = tr
         device = {"busy_s": tr["busy_s"], "window_s": tr["window_s"]}
     stats = jax.devices()[0].memory_stats() or {}

@@ -1,5 +1,6 @@
 """Each count function against arithmetic done by hand."""
-from bench.counts import attention, model, moe_ffn
+from bench.arch import moe_lm as model
+from bench.counts import attention, moe_ffn
 
 
 def test_paged_attention_counts():

@@ -86,8 +86,8 @@ def run(cell: str, seed: int, seconds: float, mc: dict, traffic: dict,
 
     make_w = weights.maker(mc)
     wkey = seeds.jax_key(seed, "weights")
-    params = program.program_params(make_w(wkey))
-    names = program.leaf_names(params)
+    params = program.program_params(mc, make_w(wkey))
+    names = program.leaf_names(mc, params)
     state = init_train_state(params, opt, "none")
     del params
     batch = gen.batch_fn(traffic, mc)
