@@ -48,6 +48,20 @@ class MoEConfig:
     impl: str = "einsum"
     moe_attention: bool = False          # M6-T 3.4 (negative result)
     expert_axis: str = "model"           # mesh axis experts are sharded over
+    # Gates are multiplied by this after any renormalisation
+    # (DeepSeek-V3's routed_scaling_factor).
+    routed_scaling_factor: float = 1.0
+    # Always-on experts beside the routed ones: one swiglu/gelu FFN of
+    # this width (n_shared * expert width), added to every token's
+    # output.  0 => none.
+    shared_d_ff: int = 0
+    # The held share: the layer routes over all ``num_experts`` at the
+    # router's width and k, holds the weights of experts
+    # [held_offset, held_offset + held_experts) only, and computes only
+    # the choices that land on them (the rest add nothing) -- one
+    # chip's share of an expert-parallel layer.  0 => every expert.
+    held_experts: int = 0
+    held_offset: int = 0
 
     def __post_init__(self):
         if self.num_experts > 0:
@@ -76,6 +90,19 @@ class MoEConfig:
                     "moe_attention=True: attention experts run the dense "
                     "einsum path, whose (G, T, E, C) view would be "
                     "O(G*T^2*E) at the dropless capacity C=T")
+            if self.held_experts and not (
+                    0 <= self.held_offset
+                    and self.held_offset + self.held_experts
+                    <= self.num_experts):
+                raise ValueError(
+                    f"held experts [{self.held_offset}, "
+                    f"{self.held_offset + self.held_experts}) do not lie "
+                    f"within the {self.num_experts} routed experts")
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights the layer holds (all unless a share)."""
+        return self.held_experts or self.num_experts
 
     @property
     def active_k(self) -> int:
@@ -135,6 +162,17 @@ class ModelConfig:
     pos_embed: str = "rope"      # rope | learned (M6/BERT style)
     rope_theta: float = 1e6
     attn_logit_softcap: float = 0.0
+    # "mha": q/k/v heads (GQA with num_kv_heads); "mla": DeepSeek-V2/V3
+    # multi-head latent attention -- keys and values come from one
+    # normed latent of kv_lora_rank lanes plus a shared roped key of
+    # qk_rope_head_dim; queries are qk_nope_head_dim + qk_rope_head_dim
+    # per head, values v_head_dim.  The paged engine caches only the
+    # latent and the roped key (576 lanes at Moonlight's widths).
+    attention: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # "auto": chunked online-softmax when S*T is large (O(S*block) memory),
     # reference otherwise; "reference"/"chunked" force a path.
     attention_impl: str = "auto"
@@ -148,6 +186,11 @@ class ModelConfig:
     # MoE
     moe: MoEConfig = dataclasses.field(default_factory=MoEConfig)
     moe_layer_period: int = 1    # apply MoE FFN every k-th layer (1 = all)
+    # Leading dense layers (DeepSeek's first_k_dense_replace): a group of
+    # its own, stacked and scanned before the MoE group, with an FFN of
+    # dense_d_ff (0 => d_ff).
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
     # enc-dec
     num_encoder_layers: int = 0
     # xLSTM
@@ -175,6 +218,31 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.attention == "mla"
+
+    @property
+    def kv_entry_dim(self) -> int:
+        """Lanes a cached token takes per KV head in the pool: the head
+        size, or for latent attention the latent plus the roped key,
+        padded with zero lanes to a multiple of 128.  Unpadded, a latent
+        entry (512 + 64 lanes) is no multiple of the TPU's 128 lanes, so
+        the pool's default device layout puts the token axis minor and
+        every step copies each layer's pool into the paged kernel's
+        layout and back."""
+        if self.mla:
+            return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+        return self.resolved_head_dim
+
+    @property
+    def layer_groups(self) -> Tuple[Tuple[str, int], ...]:
+        """(parameter key, layer count) of each stacked layer group, in
+        order: the leading dense group (if any), then the rest."""
+        nd = self.first_dense_layers
+        rest = (("blocks", self.num_layers - nd),)
+        return ((("dense_blocks", nd),) + rest) if nd else rest
 
     @property
     def activation_dtype(self):

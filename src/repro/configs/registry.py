@@ -17,6 +17,7 @@ _ARCH_MODULES = {
     "seamless-m4t-large-v2": "repro.configs.seamless_m4t_large_v2",
     "pixtral-12b": "repro.configs.pixtral_12b",
     "zamba2-7b": "repro.configs.zamba2_7b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "m6-base": "repro.configs.m6",
     "m6-10b": "repro.configs.m6",
     "m6-100b": "repro.configs.m6",
@@ -26,7 +27,12 @@ _ARCH_MODULES = {
 _M6_ATTR = {"m6-base": "M6_BASE", "m6-10b": "M6_10B",
             "m6-100b": "M6_100B", "m6-1t": "M6_1T"}
 
-ARCH_IDS = [a for a in _ARCH_MODULES if not a.startswith("m6")]
+# Served only through the paged engine: latent attention has no dense
+# (B, max_len) decode cache, which the smoke tests and the dry-run drive.
+_PAGED_ONLY = ("moonlight-16b-a3b",)
+
+ARCH_IDS = [a for a in _ARCH_MODULES
+            if not a.startswith("m6") and a not in _PAGED_ONLY]
 ALL_IDS = list(_ARCH_MODULES)
 
 

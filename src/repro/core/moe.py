@@ -30,6 +30,7 @@ token *identity* (true Hash Layers) instead of position.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -40,8 +41,10 @@ from repro.core.context import MoEContext
 from repro.core.dispatch import get_dispatcher
 from repro.core.metrics import gate_entropy
 from repro.core.routers import get_router
+from repro.core.routers.base import index_load_metrics
 from repro.core.routing import RoutingPlan, route
 from repro.distributed.sharding import shard
+from repro.models.layers import ffn_apply, ffn_specs
 from repro.nn import ParamSpec, truncated_normal_init
 
 
@@ -50,21 +53,50 @@ from repro.nn import ParamSpec, truncated_normal_init
 # ---------------------------------------------------------------------------
 
 def moe_ffn_specs(cfg: ModelConfig, d_model: Optional[int] = None):
+    """Expert stacks of the held experts (all of them unless the layer
+    holds a share), the router at its full width, the router's bias
+    where it has one, and the shared experts where the config has them."""
     m = cfg.moe
     d = d_model or cfg.d_model
     dff = cfg.d_ff
+    E = m.held
     wdt = jnp.dtype(cfg.param_dtype)
     init = truncated_normal_init(cfg.initializer_range)
     specs = {
-        "up": ParamSpec((m.num_experts, d, dff), wdt, ("expert", "embed", "mlp"), init),
-        "down": ParamSpec((m.num_experts, dff, d), wdt, ("expert", "mlp", "embed"), init),
+        "up": ParamSpec((E, d, dff), wdt, ("expert", "embed", "mlp"), init),
+        "down": ParamSpec((E, dff, d), wdt, ("expert", "mlp", "embed"), init),
     }
-    router = get_router(m.routing).param_spec(m, d, init)
-    if router is not None:
-        specs["router"] = router
+    router = get_router(m.routing)
+    spec = router.param_spec(m, d, init)
+    if spec is not None:
+        specs["router"] = spec
+    if hasattr(router, "bias_spec"):
+        specs["router_bias"] = router.bias_spec(m)
     if cfg.ffn_activation in ("swiglu", "geglu"):
-        specs["gate"] = ParamSpec((m.num_experts, d, dff), wdt, ("expert", "embed", "mlp"), init)
+        specs["gate"] = ParamSpec((E, d, dff), wdt, ("expert", "embed", "mlp"), init)
+    if m.shared_d_ff:
+        specs["shared"] = ffn_specs(cfg, m.shared_d_ff)
     return specs
+
+
+def hold_share(plan: RoutingPlan, first: int, count: int) -> RoutingPlan:
+    """The plan of a layer that holds experts [first, first + count) of
+    the ``plan.num_experts`` it routed over: choices that land on them
+    keep their gates (normalised over all of the token's choices) and
+    take local expert ids; every other choice becomes invalid, so it is
+    computed nowhere here and adds nothing.  Loads are the held
+    experts'; the drop fraction's denominator is the choices held here."""
+    local = plan.expert_index - first
+    mine = (local >= 0) & (local < count)
+    valid = plan.valid & mine
+    expert_index = jnp.where(mine, local, 0).astype(jnp.int32)
+    metrics = index_load_metrics(expert_index, valid, count,
+                                 plan.expert_index.size)
+    held = jnp.sum(mine.astype(jnp.float32))
+    metrics.update(routed_choices=held, dropped_fraction=jnp.maximum(
+        held - jnp.sum(metrics["expert_loads"]), 0.0) / jnp.maximum(held, 1.0))
+    return dataclasses.replace(plan, expert_index=expert_index, valid=valid,
+                               num_experts=count, metrics=metrics)
 
 
 # ---------------------------------------------------------------------------
@@ -111,13 +143,20 @@ def moe_ffn_apply(params, x, cfg: ModelConfig,
     router_w = params.get("router")
     if router_w is not None:
         router_w = router_w.astype(jnp.float32)
+    bias = params.get("router_bias")
     with jax.named_scope("moe_route"):
-        plan = route(xg, router_w, m, capacity, ctx=gctx)
+        plan = route(xg, router_w, m, capacity, ctx=gctx,
+                     bias=None if bias is None else bias.astype(jnp.float32))
+        if m.held_experts:
+            plan = hold_share(plan, m.held_offset, m.held_experts)
 
     with jax.named_scope(f"moe_dispatch_{m.impl}"):
         y = get_dispatcher(m.impl)(params, xg, plan, cfg, ctx=gctx)
 
     y = y.reshape(B, S, M).astype(x.dtype)
+    if m.shared_d_ff:
+        with jax.named_scope("shared_experts"):
+            y = y + ffn_apply(params["shared"], x, cfg).astype(y.dtype)
     aux = {
         "moe_aux_loss": plan.aux_loss,
         "moe_z_loss": plan.z_loss,
@@ -133,4 +172,7 @@ def moe_ffn_apply(params, x, cfg: ModelConfig,
             "routed_choices",
             jnp.asarray(float(plan.expert_index.size), jnp.float32)),
     }
+    if m.held_experts:
+        # the routed choices this layer computes: those on its experts
+        aux["moe_held_choices"] = plan.metrics["routed_choices"]
     return y, aux

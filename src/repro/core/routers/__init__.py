@@ -8,7 +8,11 @@
 * ``expert_choice`` — expert-choice routing (Zhou et al., 2022): experts
   pick their top-C tokens, perfect load balance by construction;
 * ``hash``          — stateless hash routing (Roller et al., 2021):
-  deterministic position-hash assignment, no learned router.
+  deterministic position-hash assignment, no learned router;
+* ``sigmoid_bias``  — DeepSeek-V3 sigmoid scores, top-k on scores plus a
+  correction bias, gates from the unbiased scores, renormalised and
+  scaled.  A router with a ``bias_spec`` gets its own bias leaf
+  (``router_bias``) and receives it as ``plan(..., bias=b)``.
 
 Adding a strategy is ~50 lines::
 
@@ -57,7 +61,8 @@ def available_routers() -> Tuple[str, ...]:
 
 
 # Built-ins self-register on import.
-from repro.core.routers import expert_choice, hashed, prototype, topk  # noqa: E402,F401
+from repro.core.routers import (  # noqa: E402,F401
+    expert_choice, hashed, prototype, sigmoid, topk)
 
 __all__ = [
     "Router", "RoutingPlan", "register_router", "get_router",

@@ -47,6 +47,7 @@ def route(
     cfg: MoEConfig,
     capacity: int,
     ctx: Optional[MoEContext] = None,  # (G, T)-grouped side information
+    bias: Optional[jax.Array] = None,  # the router's bias leaf, if it has one
 ) -> RoutingPlan:
     """Build the routing plan for ``cfg.routing`` via the registry.
 
@@ -57,6 +58,9 @@ def route(
     x32 = x.astype(jnp.float32)
     cd = jnp.float32 if cfg.combine_dtype == "float32" else jnp.dtype(x.dtype)
     router = get_router(cfg.routing)
+    if bias is not None:
+        return router.plan(x32, router_w, cfg, capacity, combine_dtype=cd,
+                           ctx=ctx, bias=bias)
     return router.plan(x32, router_w, cfg, capacity, combine_dtype=cd, ctx=ctx)
 
 

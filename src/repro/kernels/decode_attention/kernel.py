@@ -96,12 +96,19 @@ def decode_attention_kernel(q, k, v, lengths, *, block_kv: int = 1024,
 # Paged variant: KV lives in a shared block pool, per-row block tables
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, bs, n_b):
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, *refs, scale, bs, n_b,
+                  v_dim):
     """Same online-softmax recurrence as ``_kernel``; the KV block for
     grid step (i, h, b) is DMA'd from pool block ``tbl_ref[i, b]`` (the
     BlockSpec index maps read the scalar-prefetched table from SMEM, the
-    MegaBlocks-style trick the dropless FFN kernel uses for weights)."""
+    MegaBlocks-style trick the dropless FFN kernel uses for weights).
+
+    With ``v_dim`` (latent attention) there is no V pool: the values are
+    the leading ``v_dim`` lanes of the K tile, read once for both dots."""
+    if v_dim is None:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
     i = pl.program_id(0)
     b = pl.program_id(2)
 
@@ -125,7 +132,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        v = v_ref[0, 0].astype(jnp.float32)
+        v = (v_ref[0, 0].astype(jnp.float32) if v_dim is None
+             else k[:, :v_dim])
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -138,39 +146,65 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths, *,
+                                  scale: float, v_dim=None, layer=None,
                                   interpret: bool = False):
     """q: (N, Hkv, G, D); k_pool/v_pool: (P, Hkv, bs, D); block_tables:
-    (N, MB) int32 pool block ids per row; lengths: (N,) int32.
-    Returns (N, Hkv, G, D)."""
+    (N, MB) int32 pool block ids per row; lengths: (N,) int32; ``scale``
+    multiplies the scores.  Returns (N, Hkv, G, D).
+
+    Latent mode (``v_pool`` None, ``v_dim`` set): the values are the
+    leading ``v_dim`` lanes of each K tile; returns (N, Hkv, G, v_dim).
+    With ``layer`` (an int32 scalar, latent mode only) ``k_pool`` is a
+    layer group's whole pool (L, P, Hkv, bs, D) and the blocks are read
+    from its layer ``layer`` in place, the index riding the scalar
+    prefetch beside the tables."""
     N, Hkv, G, D = q.shape
-    _, _, bs, _ = k_pool.shape
+    bs = k_pool.shape[-2]
     MB = block_tables.shape[1]
     grid = (N, Hkv, MB)
+    Dv = D if v_pool is not None else v_dim
 
-    kernel = functools.partial(_paged_kernel, scale=D ** -0.5, bs=bs, n_b=MB)
+    kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, n_b=MB,
+                               v_dim=None if v_pool is not None else v_dim)
+    prefetch = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if layer is None:
+        kv_spec = pl.BlockSpec((1, 1, bs, D),
+                               lambda i, h, b, tbl, lens: (tbl[i, b], h, 0, 0))
+    else:
+        prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
+        kv_spec = pl.BlockSpec(
+            (None, 1, 1, bs, D),
+            lambda i, h, b, tbl, lens, lyr: (lyr[0], tbl[i, b], h, 0, 0))
+        kernel = functools.partial(_skip_layer_ref, kernel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, lengths
+        num_scalar_prefetch=len(prefetch),  # block_tables, lengths[, layer]
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda i, h, b, tbl, lens: (i, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D), lambda i, h, b, tbl, lens: (tbl[i, b], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D), lambda i, h, b, tbl, lens: (tbl[i, b], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda i, h, b, tbl, lens: (i, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda i, h, b, *_: (i, h, 0, 0)),
+            kv_spec,
+        ] + ([kv_spec] if v_pool is not None else []),
+        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda i, h, b, *_: (i, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((G, Dv), jnp.float32),
         ],
     )
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, Hkv, G, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)
+    )(*prefetch, q, *pools)
+
+
+def _skip_layer_ref(kernel, tbl_ref, len_ref, layer_ref, *refs):
+    """``kernel`` under a grid that also prefetches the layer index: only
+    the index maps read it."""
+    return kernel(tbl_ref, len_ref, *refs)
 
 
 # ---------------------------------------------------------------------------
@@ -225,19 +259,19 @@ def _quant_paged_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref, v_ref,
 
 def quantized_paged_decode_attention_kernel(q, k_pool, v_pool, k_scales,
                                             v_scales, block_tables, lengths,
-                                            *, decode,
+                                            *, decode, scale: float,
                                             interpret: bool = False):
     """q: (N, Hkv, G, D); k_pool/v_pool: (P, Hkv, bs, D) int8 codes;
     k_scales/v_scales: (P, Hkv) float32; block_tables: (N, MB) int32;
     lengths: (N,) int32; decode: the policy's code -> f32 map (must be
-    Pallas-traceable — the built-ins are astype / bitcast).
-    Returns (N, Hkv, G, D)."""
+    Pallas-traceable — the built-ins are astype / bitcast); ``scale``
+    multiplies the scores.  Returns (N, Hkv, G, D)."""
     N, Hkv, G, D = q.shape
     _, _, bs, _ = k_pool.shape
     MB = block_tables.shape[1]
     grid = (N, Hkv, MB)
 
-    kernel = functools.partial(_quant_paged_kernel, scale=D ** -0.5, bs=bs,
+    kernel = functools.partial(_quant_paged_kernel, scale=scale, bs=bs,
                                n_b=MB, decode=decode)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, lengths

@@ -39,17 +39,25 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, Hq, D)
 
 
-@jax.jit
-def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                           block_tables: jax.Array,
-                           lengths: jax.Array) -> jax.Array:
+@partial(jax.jit, static_argnames=("scale", "v_dim"))
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool,
+                           block_tables: jax.Array, lengths: jax.Array, *,
+                           scale=None, v_dim=None, layer=None) -> jax.Array:
     """q: (N, Hq, D) one query per row (decode slot, prefill-chunk
     token, or speculative verify row — rows are position-addressed, so
     several rows of one slot at consecutive positions sharing a block
     table are just more rows); k_pool/v_pool: (P, Hkv, bs, D) shared
     block pool; block_tables: (N, MB) int32 pool block ids covering each
     row's context in order; lengths: (N,) valid context per row (0 =>
-    masked row, output 0).  Returns (N, Hq, D).
+    masked row, output 0); ``scale`` multiplies the scores (None:
+    ``D ** -0.5``).  Returns (N, Hq, D).
+
+    Latent mode (multi-head latent attention): ``v_pool`` is None, the
+    pool holds one entry per token (Hkv = 1, D = latent + rope lanes)
+    that every query head reads, and the values are its leading
+    ``v_dim`` lanes; returns (N, Hq, v_dim).  With ``layer`` (an int32
+    scalar) ``k_pool`` is a layer group's pool (L, P, 1, bs, D), read at
+    that layer in place.
 
     On TPU the Pallas kernel streams only the table-addressed pool
     blocks (no dense gather); elsewhere the pure-jnp gather reference
@@ -58,17 +66,23 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     reference semantics through a full pool gather anyway).
     """
     N, Hq, D = q.shape
-    Hkv = k_pool.shape[1]
+    Hkv = k_pool.shape[-3]
+    scale = D ** -0.5 if scale is None else scale
     if jax.default_backend() != "tpu":
-        return paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths)
+        return paged_decode_attention_ref(q, k_pool, v_pool, block_tables,
+                                          lengths, scale=scale, v_dim=v_dim,
+                                          layer=layer)
     G = Hq // Hkv
     qg = q.reshape(N, Hkv, G, D)
-    out = paged_decode_attention_kernel(qg, k_pool, v_pool, block_tables, lengths)
-    return out.reshape(N, Hq, D)
+    out = paged_decode_attention_kernel(qg, k_pool, v_pool, block_tables,
+                                        lengths, scale=scale, v_dim=v_dim,
+                                        layer=layer)
+    return out.reshape(N, Hq, -1)
 
 
 def paged_update_attention(q, k, v, k_pool, v_pool, write_blocks,
-                           write_offsets, block_tables, lengths):
+                           write_offsets, block_tables, lengths, *,
+                           scale=None, v_dim=None, layer=None):
     """One serving step's K/V write + paged attention, fused at the op
     level: scatter this step's per-row K/V at ``(write_blocks, :,
     write_offsets)``, then attend through the block tables.  The write
@@ -78,11 +92,24 @@ def paged_update_attention(q, k, v, k_pool, v_pool, write_blocks,
 
     q: (N, Hq, D); k/v: (N, Hkv, D); pools: (P, Hkv, bs, D);
     write_blocks/write_offsets: (N,) pool coords (masked rows target the
-    garbage block); block_tables: (N, MB); lengths: (N,).
+    garbage block); block_tables: (N, MB); lengths: (N,).  Latent mode:
+    ``v`` and ``v_pool`` None, ``k`` each row's latent entry, ``v_dim``
+    the value lanes, and with ``layer`` the write and the read go to
+    that layer of a layer group's pool (L, P, 1, bs, D), in place (see
+    :func:`paged_decode_attention`).
     """
+    if layer is not None:
+        k_pool = k_pool.at[layer, write_blocks, :, write_offsets].set(
+            k.astype(k_pool.dtype))
+        out = paged_decode_attention(q, k_pool, None, block_tables, lengths,
+                                     scale=scale, v_dim=v_dim, layer=layer)
+        return out, k_pool, None
     k_pool = k_pool.at[write_blocks, :, write_offsets].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[write_blocks, :, write_offsets].set(v.astype(v_pool.dtype))
-    out = paged_decode_attention(q, k_pool, v_pool, block_tables, lengths)
+    if v_pool is not None:
+        v_pool = v_pool.at[write_blocks, :, write_offsets].set(
+            v.astype(v_pool.dtype))
+    out = paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
+                                 scale=scale, v_dim=v_dim)
     return out, k_pool, v_pool
 
 
@@ -101,12 +128,12 @@ def quantized_paged_decode_attention(q, k_pool, v_pool, k_scales, v_scales,
     if jax.default_backend() != "tpu":
         return quantized_paged_decode_attention_ref(
             q, k_pool, v_pool, k_scales, v_scales, block_tables, lengths,
-            policy=policy)
+            policy=policy, scale=D ** -0.5)
     G = Hq // Hkv
     qg = q.reshape(N, Hkv, G, D)
     out = quantized_paged_decode_attention_kernel(
         qg, k_pool, v_pool, k_scales, v_scales, block_tables, lengths,
-        decode=policy.decode)
+        decode=policy.decode, scale=D ** -0.5)
     return out.reshape(N, Hq, D)
 
 

@@ -16,6 +16,7 @@ from repro.nn import ParamSpec, ones_init
 from repro.configs.base import ModelConfig
 from repro.models.layers import (
     apply_rope,
+    apply_rope_interleaved,
     dense_specs,
     dense_apply,
     head_rmsnorm_apply,
@@ -200,3 +201,100 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> KV
         jax.ShapeDtypeStruct(shape, dtype),
         jax.ShapeDtypeStruct((), jnp.int32),
     )
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3, no query LoRA)
+# ---------------------------------------------------------------------------
+#
+#   q = x Wq, split per head into q_nope (nope) and q_pe (rope lanes);
+#   [c | k_pe] = x Wkv_a, c of kv_lora_rank lanes through an RMSNorm;
+#   [k_nope | v] = c Wkv_b per head; rope (interleaved pairs) on q_pe and
+#   on the one k_pe all heads share; softmax scale (nope + rope)^-0.5;
+#   o = attn Wo.
+#
+# The full-sequence form builds k and v per head.  The absorbed form,
+# which the paged engine caches, keeps only [c | k_pe] per token: the
+# query's nope part is taken into the latent (q_nope Wuk, ``mla_q_absorb``)
+# so that a score is one dot product over kv_lora_rank + rope lanes with
+# the cached entry, the value is the entry's leading kv_lora_rank lanes,
+# and each head's latent output goes through Wuv (``mla_v_up``).
+
+def mla_specs(cfg: ModelConfig):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {
+        "wq": dense_specs(cfg, d, H * qk, ("embed", "heads")),
+        "wkv_a": dense_specs(cfg, d, r + cfg.qk_rope_head_dim, ("embed", None)),
+        "kv_norm": ParamSpec((r,), jnp.float32, (None,), ones_init),
+        "wkv_b": dense_specs(cfg, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+                             (None, "heads")),
+        "wo": dense_specs(cfg, H * cfg.v_head_dim, d, ("heads", "embed")),
+    }
+
+
+def mla_project(params, x, cfg: ModelConfig, positions):
+    """x: (B, S, d) -> q_nope (B, S, H, nope), q_pe (B, S, H, rope) with
+    rope applied, and the cached entry (B, S, kv_lora_rank + rope): the
+    normed latent followed by the roped shared key."""
+    B, S, _ = x.shape
+    n, rp, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    q = dense_apply(params["wq"], x, cfg).reshape(B, S, cfg.num_heads, n + rp)
+    kv = dense_apply(params["wkv_a"], x, cfg)
+    c = head_rmsnorm_apply(params["kv_norm"], kv[..., :r], cfg.norm_eps)
+    sin, cos = rope(positions, rp, cfg.rope_theta)
+    q_pe = apply_rope_interleaved(q[..., n:], sin, cos)
+    k_pe = apply_rope_interleaved(kv[..., None, r:], sin, cos)[..., 0, :]
+    return q[..., :n], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def _mla_up(params, cfg: ModelConfig):
+    """Wkv_b as (kv_lora_rank, H, nope) key and (kv_lora_rank, H, v)
+    value up-projections."""
+    w = params["wkv_b"]["kernel"].astype(cfg.activation_dtype)
+    w = w.reshape(cfg.kv_lora_rank, cfg.num_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def mla_absorb_q(params, q_nope, q_pe, cfg: ModelConfig):
+    """The absorbed query: q_nope Wuk per head, then the roped part:
+    (..., H, kv_lora_rank + rope), one dot product with a cached entry."""
+    w_uk, _ = _mla_up(params, cfg)
+    with jax.named_scope("mla_q_absorb"):
+        q_lat = jnp.einsum("...hn,rhn->...hr", q_nope, w_uk)
+    return jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)
+
+
+def mla_v_up(params, o_lat, cfg: ModelConfig):
+    """Each head's latent output (..., H, kv_lora_rank) through Wuv:
+    (..., H, v_head_dim)."""
+    _, w_uv = _mla_up(params, cfg)
+    with jax.named_scope("mla_v_up"):
+        return jnp.einsum("...hr,rhv->...hv", o_lat.astype(w_uv.dtype), w_uv)
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def mla_attention_apply(params, x, cfg: ModelConfig, *, positions,
+                        causal: bool = True) -> jax.Array:
+    """Full-sequence latent attention in its plain (non-absorbed) form:
+    keys and values per head from the latent, as in training."""
+    B, S, _ = x.shape
+    H, n, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q_nope, q_pe, entry = mla_project(params, x, cfg, positions)
+    kv = jnp.einsum("bsr,rhe->bshe", entry[..., :r],
+                    params["wkv_b"]["kernel"].astype(cfg.activation_dtype)
+                    .reshape(r, H, -1))
+    k_pe = jnp.broadcast_to(entry[..., None, r:], q_pe.shape)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :n], k_pe], axis=-1)
+    v = kv[..., n:]
+    dv = v.shape[-1]
+    # _sdpa scales by q's width, (nope + rope)^-0.5, and returns q's
+    # width: pad the values with zero lanes and drop them after
+    v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - dv),))
+    mask = causal_mask(S, S) if causal else None
+    out = _sdpa(q, k, v, cfg, mask, causal_offset=0 if causal else None)
+    return dense_apply(params["wo"], out[..., :dv].reshape(B, S, H * dv), cfg)

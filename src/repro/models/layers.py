@@ -107,6 +107,17 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(dtype)
 
 
+def apply_rope_interleaved(x: jax.Array, sin: jax.Array,
+                           cos: jax.Array) -> jax.Array:
+    """Rotary positions on interleaved pairs ``(x[2i], x[2i+1])`` at
+    frequency ``i`` (DeepSeek-V2/V3): the channels are de-interleaved
+    (evens, then odds) and rotated half against half, as
+    ``modeling_deepseek.apply_rotary_pos_emb`` does."""
+    D = x.shape[-1]
+    x = jnp.swapaxes(x.reshape(*x.shape[:-1], D // 2, 2), -1, -2)
+    return apply_rope(x.reshape(*x.shape[:-2], D), sin, cos)
+
+
 # ---------------------------------------------------------------------------
 # Dense projections / FFN
 # ---------------------------------------------------------------------------

@@ -29,6 +29,8 @@ from repro.models.attention import (
     attention_apply,
     attention_specs,
     init_cache,
+    mla_attention_apply,
+    mla_specs,
 )
 from repro.nn.spec import stack_specs
 
@@ -44,12 +46,14 @@ def block_specs(cfg: ModelConfig, moe_layer: bool):
     }
     if cfg.moe.moe_attention and moe_layer:
         specs["attn"] = moe_attention_specs(cfg)
+    elif cfg.mla:
+        specs["attn"] = mla_specs(cfg)
     else:
         specs["attn"] = attention_specs(cfg)
     if moe_layer:
         specs["ffn"] = moe_ffn_specs(cfg)
     else:
-        specs["ffn"] = L.ffn_specs(cfg)
+        specs["ffn"] = L.ffn_specs(cfg, cfg.dense_d_ff or None)
     return specs
 
 
@@ -67,6 +71,14 @@ def block_apply(params, x, cfg: ModelConfig, *, positions, moe_layer: bool,
         attn_out, attn_aux = moe_attention_apply(params["attn"], h, cfg,
                                                  positions=positions, ctx=ctx)
         new_cache = None
+    elif cfg.mla:
+        if cache is not None:
+            raise NotImplementedError(
+                "latent attention decodes through the paged engine "
+                "(repro.serving.continuous.ContinuousEngine) only")
+        attn_out = mla_attention_apply(params["attn"], h, cfg,
+                                       positions=positions)
+        new_cache = attn_aux = None
     else:
         attn_out, new_cache = attention_apply(
             params["attn"], h, cfg, positions=positions, cache=cache, use_flash=use_flash)
@@ -81,8 +93,9 @@ def block_apply(params, x, cfg: ModelConfig, *, positions, moe_layer: bool,
             aux = {k: aux[k] + attn_aux[k] if k.endswith("_loss") else aux[k]
                    for k in aux}
     else:
-        ffn_out, aux = (L.ffn_apply(params["ffn"], h, cfg),
-                        empty_aux(cfg.moe.num_experts))
+        with jax.named_scope("dense_ffn"):
+            ffn_out = L.ffn_apply(params["ffn"], h, cfg)
+        aux = empty_aux(cfg.moe.held)
     x = x + ffn_out
     x = shard(x, "batch", "seq", "embed")
     return x, aux, new_cache
@@ -100,7 +113,11 @@ def lm_specs(cfg: ModelConfig):
         specs["pos_embed"] = ParamSpec(
             (cfg.max_seq_len, cfg.d_model), jnp.dtype(cfg.param_dtype),
             (None, "embed"), truncated_normal_init(cfg.initializer_range))
-    if cfg.scan_layers and uniform:
+    if cfg.first_dense_layers:
+        # a leading dense group, then the MoE group, each stacked
+        for key, n in cfg.layer_groups:
+            specs[key] = stack_specs(block_specs(cfg, key == "blocks"), n)
+    elif cfg.scan_layers and uniform:
         specs["blocks"] = stack_specs(block_specs(cfg, _is_moe_layer(cfg, 0)), cfg.num_layers)
     else:
         specs["blocks"] = [block_specs(cfg, _is_moe_layer(cfg, i)) for i in range(cfg.num_layers)]
@@ -118,6 +135,37 @@ def _run_blocks(params, x, cfg: ModelConfig, *, positions, caches=None,
     """
     uniform = cfg.moe.num_experts == 0 or cfg.moe_layer_period == 1
     decode = caches is not None
+
+    def scan_layers(x, blocks, moe_layer: bool):
+        def body(h, bp):
+            h, aux, _ = block_apply(bp, h, cfg, positions=positions,
+                                    moe_layer=moe_layer, cache=None,
+                                    use_flash=use_flash, ctx=ctx)
+            return h, aux
+
+        if cfg.remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        return jax.lax.scan(body, x, blocks)
+
+    if cfg.first_dense_layers:
+        if decode:
+            raise NotImplementedError(
+                "layer groups decode through the paged engine only")
+        auxes = []
+        for key, _ in cfg.layer_groups:
+            x, aux = scan_layers(x, params[key], moe_layer=key == "blocks")
+            auxes.append(aux)
+        # per-layer metrics, the dense group's zeros first; a key only
+        # the MoE group reports (the held-choice count) reads 0 there
+        sizes = [n for _, n in cfg.layer_groups]
+        ref = auxes[-1]
+        aux = {k: (sum(jnp.sum(a[k]) for a in auxes if k in a)
+                   if k.endswith("_loss") else jnp.concatenate(
+                       [a[k] if k in a else jnp.zeros((n,) + ref[k].shape[1:],
+                                                      ref[k].dtype)
+                        for a, n in zip(auxes, sizes)]))
+               for k in ref}
+        return x, aux, None
 
     if isinstance(params["blocks"], list):  # unrolled (mixed layer kinds)
         auxes, new_caches = [], []
@@ -149,15 +197,7 @@ def _run_blocks(params, x, cfg: ModelConfig, *, positions, caches=None,
 
         x, (aux, new_caches) = jax.lax.scan(body, x, (params["blocks"], caches))
     else:
-        def body(h, bp):
-            h, aux, _ = block_apply(bp, h, cfg, positions=positions,
-                                    moe_layer=moe_layer, cache=None,
-                                    use_flash=use_flash, ctx=ctx)
-            return h, aux
-
-        if cfg.remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        x, aux = jax.lax.scan(body, x, params["blocks"])
+        x, aux = scan_layers(x, params["blocks"], moe_layer)
         new_caches = None
     aux = dict(aux)
     for k in list(aux):

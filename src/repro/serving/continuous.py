@@ -87,7 +87,13 @@ from repro.kernels.decode_attention import (
     sharded_quantized_paged_update_attention,
 )
 from repro.models import layers as L
-from repro.models.attention import _project_qkv
+from repro.models.attention import (
+    _project_qkv,
+    mla_absorb_q,
+    mla_project,
+    mla_scale,
+    mla_v_up,
+)
 from repro.models.registry import get_family
 from repro.models.transformer import _is_moe_layer
 from repro.nn import ParamSpec
@@ -108,7 +114,7 @@ _RECURRENT_FAMILIES = ("xlstm",)
 
 def _paged_block(bp, x, cfg: ModelConfig, *, moe_layer: bool, positions,
                  lengths, row_tables, wb, wo, kp, vp, ctx, mesh=None,
-                 ksc=None, vsc=None, policy=None):
+                 ksc=None, vsc=None, policy=None, layer=None):
     """One pre-norm block over the flat row batch ``x: (1, N, d)``.
 
     K/V for every row are written into the pool at (wb, wo) *before* the
@@ -121,10 +127,63 @@ def _paged_block(bp, x, cfg: ModelConfig, *, moe_layer: bool, positions,
     cover its own slots) and (wb, wo)/row_tables carry shard-local block
     ids into the shard's private pool slice.  This is sequential with —
     never nested inside — the MoE dispatcher's own shard_map.
+
+    With ``layer`` (latent attention), ``kp`` is the layer group's whole
+    latent pool and the block writes and reads its layer ``layer``.
     """
     N = x.shape[1]
     h = L.norm_apply(bp["ln_attn"], x, cfg)
-    q, k, v = _project_qkv(bp["attn"], h, cfg, positions)       # (1, N, H*, D)
+    if cfg.mla:
+        out, kp = _paged_latent_attention(bp["attn"], h, cfg, positions,
+                                          lengths, row_tables, wb, wo, kp,
+                                          layer)
+    else:
+        out, kp, vp, ksc, vsc = _paged_mha(bp["attn"], h, cfg, positions,
+                                           lengths, row_tables, wb, wo, kp,
+                                           vp, mesh, ksc, vsc, policy)
+    attn_out = L.dense_apply(bp["attn"]["wo"], out.reshape(1, N, -1), cfg)
+    x = x + attn_out
+    x = shard(x, "batch", "seq", "embed")
+
+    h = L.norm_apply(bp["ln_ffn"], x, cfg)
+    if moe_layer:
+        with jax.named_scope("moe_ffn"):
+            ffn_out, aux = moe_ffn_apply(bp["ffn"], h, cfg, ctx=ctx)
+        telem = _layer_telemetry(aux, cfg)
+    else:
+        with jax.named_scope("dense_ffn"):
+            ffn_out = L.ffn_apply(bp["ffn"], h, cfg)
+        telem = _layer_telemetry(None, cfg)
+    x = x + ffn_out
+    x = shard(x, "batch", "seq", "embed")
+    return x, kp, vp, ksc, vsc, telem
+
+
+def _paged_latent_attention(ap, h, cfg: ModelConfig, positions, lengths,
+                            row_tables, wb, wo, kp, layer):
+    """Absorbed latent attention over layer ``layer`` of the layer
+    group's latent pool ``kp`` (no V pool): each row's entry [normed
+    latent | roped key], padded with the pool's zero lanes, is written
+    at (wb, wo), then its absorbed query reads the slot's entries
+    through the block table in the one paged op.  Returns (per-head
+    values (N, H, v), kp)."""
+    q_nope, q_pe, entry = mla_project(ap, h, cfg, positions)
+    q = mla_absorb_q(ap, q_nope, q_pe, cfg)[0]               # (N, H, r + rope)
+    pad = kp.shape[-1] - q.shape[-1]        # the pool's zero lanes (kv_entry_dim)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pad)))
+    entry = jnp.pad(entry[0], ((0, 0), (0, pad)))
+    with jax.named_scope("paged_attention"):
+        o_lat, kp, _ = paged_update_attention(
+            q, entry[:, None], None, kp, None, wb, wo, row_tables, lengths,
+            scale=mla_scale(cfg), v_dim=cfg.kv_lora_rank, layer=layer)
+    return mla_v_up(ap, o_lat, cfg), kp
+
+
+def _paged_mha(ap, h, cfg: ModelConfig, positions, lengths, row_tables, wb,
+               wo, kp, vp, mesh, ksc, vsc, policy):
+    """Attention with K and V heads: write this step's K/V, read through
+    the block tables (quantized and/or under the mesh where asked)."""
+    q, k, v = _project_qkv(ap, h, cfg, positions)               # (1, N, H*, D)
     with jax.named_scope("paged_attention"):
         if policy is not None:
             if mesh is None:
@@ -143,39 +202,34 @@ def _paged_block(bp, x, cfg: ModelConfig, *, moe_layer: bool, positions,
             out, kp, vp = sharded_paged_update_attention(
                 q[0], k[0], v[0], kp, vp, wb, wo, row_tables, lengths,
                 mesh=mesh, axis="data")
-    attn_out = L.dense_apply(bp["attn"]["wo"], out.reshape(1, N, -1), cfg)
-    x = x + attn_out
-    x = shard(x, "batch", "seq", "embed")
-
-    h = L.norm_apply(bp["ln_ffn"], x, cfg)
-    if moe_layer:
-        with jax.named_scope("moe_ffn"):
-            ffn_out, aux = moe_ffn_apply(bp["ffn"], h, cfg, ctx=ctx)
-        telem = _layer_telemetry(aux, cfg.moe.num_experts)
-    else:
-        ffn_out = L.ffn_apply(bp["ffn"], h, cfg)
-        telem = _layer_telemetry(None, cfg.moe.num_experts)
-    x = x + ffn_out
-    x = shard(x, "batch", "seq", "embed")
-    return x, kp, vp, ksc, vsc, telem
+    return out, kp, vp, ksc, vsc
 
 
-def _layer_telemetry(aux, num_experts: int) -> dict:
+def _layer_telemetry(aux, cfg: ModelConfig) -> dict:
     """Per-layer routing telemetry with a shape uniform across MoE and
     dense layers, so the per-layer stack (scan ys or manual) is a clean
-    ``(L, ...)`` pytree.  Dense layers contribute exact zeros."""
+    ``(L, ...)`` pytree.  Dense layers contribute exact zeros.  A layer
+    that holds a share of its experts reports its held experts' loads
+    and ``held_choices``, the routed choices it computes."""
+    held = cfg.moe.held_experts
     if aux is None:
-        return {"expert_tokens": jnp.zeros((num_experts,), jnp.float32),
-                "gate_entropy": jnp.zeros((), jnp.float32),
-                "dropped": jnp.zeros((), jnp.float32),
-                "routed_choices": jnp.zeros((), jnp.float32)}
+        out = {"expert_tokens": jnp.zeros((cfg.moe.held,), jnp.float32),
+               "gate_entropy": jnp.zeros((), jnp.float32),
+               "dropped": jnp.zeros((), jnp.float32),
+               "routed_choices": jnp.zeros((), jnp.float32)}
+        if held:
+            out["held_choices"] = jnp.zeros((), jnp.float32)
+        return out
     choices = aux["moe_routed_choices"]
-    return {"expert_tokens": aux["moe_expert_tokens"],
-            "gate_entropy": aux["moe_gate_entropy"],
-            # drop *count* (fraction × denominator): summable across
-            # steps, and exactly 0.0 when the fraction is exactly 0.0
-            "dropped": aux["moe_dropped_fraction"] * choices,
-            "routed_choices": choices}
+    out = {"expert_tokens": aux["moe_expert_tokens"],
+           "gate_entropy": aux["moe_gate_entropy"],
+           # drop *count* (fraction × denominator): summable across
+           # steps, and exactly 0.0 when the fraction is exactly 0.0
+           "dropped": aux["moe_dropped_fraction"] * choices,
+           "routed_choices": choices}
+    if held:
+        out["held_choices"] = aux["moe_held_choices"]
+    return out
 
 
 def _paged_logits(params, cfg: ModelConfig, tokens, ctx_ids, positions,
@@ -203,7 +257,29 @@ def _paged_logits(params, cfg: ModelConfig, tokens, ctx_ids, positions,
 
     blocks = params["blocks"]
     quantized = policy is not None
-    if isinstance(blocks, (list, tuple)):       # unrolled (mixed layer kinds)
+    if cfg.first_dense_layers:
+        # layer groups (a leading dense group, then the MoE group), each
+        # scanned over its stacked weights with its latent pool carried
+        # whole: a layer writes and reads its own layer of the pool in
+        # place, so the step copies no pool
+        k_pools, telems = list(k_pools), []
+        for g, (key, n) in enumerate(cfg.layer_groups):
+            def body(carry, scanned, moe_layer=key == "blocks"):
+                h, kp = carry
+                bp, i = scanned
+                h, kp, _, _, _, tl = _paged_block(
+                    bp, h, cfg, moe_layer=moe_layer, positions=pos2,
+                    lengths=lengths, row_tables=row_tables, wb=wb, wo=wo,
+                    kp=kp, vp=None, ctx=ctx, mesh=mesh, layer=i)
+                return (h, kp), tl
+
+            (x, k_pools[g]), tl = jax.lax.scan(
+                body, (x, k_pools[g]), (params[key], jnp.arange(n)))
+            telems.append(tl)
+        k_pools = tuple(k_pools)
+        telem = {k: jnp.concatenate([t[k] for t in telems])
+                 for k in telems[0]}
+    elif isinstance(blocks, (list, tuple)):     # unrolled (mixed layer kinds)
         ks, vs, kss, vss, telems = [], [], [], [], []
         for i, bp in enumerate(blocks):
             x, kp, vp, ksc, vsc, tl = _paged_block(
@@ -368,6 +444,24 @@ class ContinuousEngine:
             if cfg.moe.moe_attention:
                 raise NotImplementedError(
                     "moe_attention has no cached decode path")
+            if cfg.first_dense_layers and not cfg.mla:
+                raise NotImplementedError(
+                    "layer groups are served with latent attention only")
+            if cfg.mla or cfg.first_dense_layers:
+                # the latent pool (and a pool per layer group) is read and
+                # written only by the plain paged step
+                for on, what in (
+                        (serve.prefix_cache, "prefix caching"),
+                        (serve.kv_quant != "none", "a quantized KV cache"),
+                        (serve.spec is not None, "speculative decoding"),
+                        (serve.mesh is not None, "a serving mesh"),
+                        (serve.slo is not None and serve.slo.preemption,
+                         "preemption with KV swap")):
+                    if on:
+                        raise NotImplementedError(
+                            f"{what} is not supported with latent attention "
+                            f"or layer groups: their pools take the plain "
+                            f"paged step only")
         elif cfg.family in _RECURRENT_FAMILIES:
             self.mode = "recurrent"
         else:
@@ -646,6 +740,8 @@ class ContinuousEngine:
                "gate_entropy": telem["gate_entropy"] * float(rows),  # (L,)
                "dropped": telem["dropped"],                      # (L,)
                "routed_choices": telem["routed_choices"]}        # (L,)
+        if "held_choices" in telem:                              # (L,)
+            add["held_choices"] = telem["held_choices"]
         if self._moe_acc is None:
             self._moe_acc = add
         else:
@@ -679,6 +775,9 @@ class ContinuousEngine:
             m.gauge("moe_gate_entropy", layer=layer).set(ent[layer] / rows)
             m.gauge("moe_dropped_fraction", layer=layer).set(
                 drop[layer] / choices[layer])
+            if "held_choices" in acc:
+                m.counter("moe_held_choices_total", layer=layer).set_to(
+                    float(acc["held_choices"][layer]))
         total_choices = choices.sum()
         moe_layers = choices > 0
         loads = tok[moe_layers].sum(axis=0)

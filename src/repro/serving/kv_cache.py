@@ -130,23 +130,37 @@ class PagedKVCache:
 
     def _alloc_pools(self, cfg: ModelConfig, serve: ServeConfig) -> None:
         """Create the device pools.  The quantized variants override
-        this with int8 code pools plus float32 scale pools."""
-        hd = cfg.resolved_head_dim
-        pool_shape = (cfg.num_layers, self.num_blocks + 1, cfg.num_kv_heads,
-                      self.block_size, hd)
+        this with int8 code pools plus float32 scale pools.
+
+        Latent attention (``cfg.mla``) keeps one latent pool of
+        ``(L, P, 1, bs, kv_entry_dim)`` (the latent and the roped key,
+        padded to 128 lanes) and no V pool: the values are the entries'
+        leading lanes.  A config with layer groups (a leading dense
+        group) holds its pool as one array per group, in group order:
+        each group's layer scan carries its pool whole and each layer's
+        paged op writes and reads its own layer of it in place, so the
+        step neither splits, joins nor slices a pool."""
+        pool_shape = (self.num_blocks + 1, cfg.num_kv_heads, self.block_size,
+                      cfg.kv_entry_dim)
         dtype = cfg.activation_dtype
-        self.k_pool = jnp.zeros(pool_shape, dtype)
-        self.v_pool = jnp.zeros(pool_shape, dtype)
+
+        def pool():
+            pools = tuple(jnp.zeros((n,) + pool_shape, dtype)
+                          for _, n in cfg.layer_groups)
+            return pools if len(pools) > 1 else pools[0]
+
+        self.k_pool = pool()
+        self.v_pool = None if cfg.mla else pool()
 
     @property
     def block_bytes(self) -> int:
-        """Device bytes one KV block costs across all layers (K + V).
-        Computed from the config (not the live pools) so detached
-        sub-caches report it too."""
+        """Device bytes one KV block costs across all layers (K + V, or
+        the latent entry alone).  Computed from the config (not the live
+        pools) so detached sub-caches report it too."""
         cfg = self.cfg
-        per_entry = (cfg.num_kv_heads * self.block_size
-                     * cfg.resolved_head_dim)
-        return 2 * cfg.num_layers * per_entry * cfg.activation_dtype.itemsize
+        per_entry = (cfg.num_kv_heads * self.block_size * cfg.kv_entry_dim)
+        pools = 1 if cfg.mla else 2
+        return pools * cfg.num_layers * per_entry * cfg.activation_dtype.itemsize
 
     def blocks_needed(self, total_len: int) -> int:
         return -(-total_len // self.block_size)

@@ -193,10 +193,10 @@ def test_quantized_kernel_matches_ref(name):
     # ref takes flat (N, Hq, D) queries, the kernel grouped (N, Hkv, G, D)
     ref = quantized_paged_decode_attention_ref(
         q.reshape(N, H * G, D), k_pool, v_pool, k_scales, v_scales, tbl,
-        lens, policy=policy)
+        lens, policy=policy, scale=D ** -0.5)
     out = quantized_paged_decode_attention_kernel(
         q, k_pool, v_pool, k_scales, v_scales, tbl, lens,
-        decode=policy.decode, interpret=True)
+        decode=policy.decode, scale=D ** -0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(out).reshape(N, H * G, D),
                                np.asarray(ref), rtol=2e-5, atol=2e-5)
 

@@ -97,10 +97,10 @@ def test_paged_kernel_interpret_matches_ref():
     out = paged_decode_attention_kernel(
         jnp.asarray(q).reshape(N, Hkv, G, D), jnp.asarray(k_pool),
         jnp.asarray(v_pool), jnp.asarray(tables), jnp.asarray(lengths),
-        interpret=True).reshape(N, Hkv * G, D)
+        scale=D ** -0.5, interpret=True).reshape(N, Hkv * G, D)
     ref = paged_decode_attention_ref(jnp.asarray(q), jnp.asarray(k_pool),
                                      jnp.asarray(v_pool), jnp.asarray(tables),
-                                     jnp.asarray(lengths))
+                                     jnp.asarray(lengths), scale=D ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 

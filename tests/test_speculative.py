@@ -266,11 +266,11 @@ def test_paged_kernel_interpret_verify_shape():
     out = paged_decode_attention_kernel(
         jnp.asarray(q).reshape(N, Hkv, Hq // Hkv, D), jnp.asarray(k_pool),
         jnp.asarray(v_pool), jnp.asarray(row_tables), jnp.asarray(lengths),
-        interpret=True).reshape(N, Hq, D)
+        scale=D ** -0.5, interpret=True).reshape(N, Hq, D)
     ref = paged_decode_attention_ref(jnp.asarray(q), jnp.asarray(k_pool),
                                      jnp.asarray(v_pool),
                                      jnp.asarray(row_tables),
-                                     jnp.asarray(lengths))
+                                     jnp.asarray(lengths), scale=D ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 

@@ -11,6 +11,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and a test worker that
 holds it keeps it until it exits.
 """
+import functools
 import os
 
 import jax
@@ -79,8 +80,28 @@ def _paged_shapes(widths, pool_dtype):
 @pytest.mark.parametrize("widths", sorted(ATTN_WIDTHS))
 def test_paged_attention_compiles(one_chip, widths):
     shapes, _ = _paged_shapes(widths, jnp.bfloat16)
-    _assert_kernel(_compile_text(paged_decode_attention_kernel, one_chip,
-                                 *shapes))
+    d = ATTN_WIDTHS[widths][2]
+    _assert_kernel(_compile_text(
+        functools.partial(paged_decode_attention_kernel, scale=d ** -0.5),
+        one_chip, *shapes))
+
+
+def test_latent_paged_attention_compiles(one_chip):
+    """Moonlight's absorbed decode: 16 query heads over one latent KV
+    head of 512 + 64 lanes (zero-padded to 640) in blocks of 256, the
+    values the leading 512, read in place from layer ``layer`` of a
+    4-layer group's pool."""
+    n, blocks, bs = 384, 64, 256
+
+    def fn(q, k, tbl, lens, layer):
+        return paged_decode_attention_kernel(q, k, None, tbl, lens,
+                                             scale=192 ** -0.5, v_dim=512,
+                                             layer=layer)
+
+    _assert_kernel(_compile_text(
+        fn, one_chip, ((n, 1, 16, 640), jnp.bfloat16),
+        ((4, blocks, 1, bs, 640), jnp.bfloat16), ((n, 8192 // bs), jnp.int32),
+        ((n,), jnp.int32), ((), jnp.int32)))
 
 
 @pytest.mark.parametrize("kv_quant", ["int8", "fp8"])
@@ -91,7 +112,8 @@ def test_quantized_paged_attention_compiles(one_chip, kv_quant):
 
     def fn(q, k, v, ks, vs, tbl, lens):
         return quantized_paged_decode_attention_kernel(
-            q, k, v, ks, vs, tbl, lens, decode=decode)
+            q, k, v, ks, vs, tbl, lens, decode=decode,
+            scale=q.shape[-1] ** -0.5)
 
     _assert_kernel(_compile_text(fn, one_chip, q, k, v, scales, scales,
                                  tbl, lens))
