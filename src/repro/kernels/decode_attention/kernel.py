@@ -96,12 +96,64 @@ def decode_attention_kernel(q, k, v, lengths, *, block_kv: int = 1024,
 # Paged variant: KV lives in a shared block pool, per-row block tables
 # ---------------------------------------------------------------------------
 
+def _held_tables(block_tables, lengths, *, bs):
+    """Each row's table with the entries past its last valid block set
+    to that block (an empty row: entry 0).  Grid step (i, b) reads entry
+    b, so past a row's length the block index repeats, the pipeline
+    issues no DMA, and no entry past the length is ever read.  Computed
+    once per call, outside the kernel: the index maps stay one SMEM
+    load a step."""
+    last = jnp.maximum(lengths - 1, 0) // bs
+    entry = jnp.minimum(jnp.arange(block_tables.shape[1], dtype=jnp.int32),
+                        last[:, None])
+    return jnp.take_along_axis(block_tables, entry, axis=1)
+
+
+def _vpu_tile(q, k, v, cols, m_prev, *, length):
+    """One query per KV head (G = 1): scores as a lane reduction of
+    ``q * k`` over D and ``p . v`` as a sublane reduction over the block,
+    on the VPU.  A (1, D) x (D, bs) MXU product per head would latch a
+    whole tile for one row.  q: (Hkv, 1, D); k: (Hkv, bs, D); v: (Hkv,
+    bs, Dv); m_prev: (Hkv, 1, 1).  Returns (m_new, p summed, p . v)."""
+    s = jnp.sum(q * k, axis=-1, keepdims=True)                   # (Hkv, bs, 1)
+    s = jnp.where(cols[..., None] < length, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    return (m_new, jnp.sum(p, axis=1, keepdims=True),
+            jnp.sum(p * v, axis=1, keepdims=True))
+
+
+def _mxu_tile(q, k, v, cols, m_prev, *, length):
+    """Several queries per KV head (GQA, latent mode): the head's K tile
+    is reused by its G queries on the MXU, one head at a time.  Shapes as
+    ``_vpu_tile`` with G queries: q: (Hkv, G, D); m_prev: (Hkv, G, 1)."""
+    m_new, l_blk, pv = [], [], []
+    for h in range(q.shape[0]):
+        s = jax.lax.dot_general(q[h], k[h], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (G, bs)
+        s = jnp.where(cols < length, s, NEG_INF)
+        m = jnp.maximum(m_prev[h], jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m)
+        m_new.append(m)
+        l_blk.append(jnp.sum(p, axis=-1, keepdims=True))
+        pv.append(jnp.dot(p, v[h], preferred_element_type=jnp.float32))
+    return jnp.stack(m_new), jnp.stack(l_blk), jnp.stack(pv)
+
+
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, *refs, scale, bs, n_b,
                   v_dim):
-    """Same online-softmax recurrence as ``_kernel``; the KV block for
-    grid step (i, h, b) is DMA'd from pool block ``tbl_ref[i, b]`` (the
-    BlockSpec index maps read the scalar-prefetched table from SMEM, the
+    """Same online-softmax recurrence as ``_kernel``, for every KV head of
+    row ``i`` at once: grid step (i, b) holds pool block ``tbl[i, b]``
+    whole, (Hkv, bs, D) contiguous, DMA'd through the BlockSpec index
+    maps that read the scalar-prefetched (held) table from SMEM (the
     MegaBlocks-style trick the dropless FFN kernel uses for weights).
+    Blocks past the row's length are skipped; the online softmax state is
+    (Hkv, G, 1) and (Hkv, G, Dv) float32.
+
+    The tile arithmetic follows G, a shape the kernel sees: with one
+    query per KV head (``_vpu_tile``) the VPU computes the scores and
+    ``p . v``; with several (``_mxu_tile``) the MXU does.  Both run in
+    float32 on the upcast tiles.
 
     With ``v_dim`` (latent attention) there is no V pool: the values are
     the leading ``v_dim`` lanes of the K tile, read once for both dots."""
@@ -110,7 +162,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, *refs, scale, bs, n_b,
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
     i = pl.program_id(0)
-    b = pl.program_id(2)
+    b = pl.program_id(1)
 
     @pl.when(b == 0)
     def _init():
@@ -121,28 +173,24 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, *refs, scale, bs, n_b,
     length = len_ref[i]
     @pl.when(b * bs < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, bs)
-        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < length, s, NEG_INF)
+        q = q_ref[0].astype(jnp.float32) * scale             # (Hkv, G, D)
+        k = k_ref[0].astype(jnp.float32)                     # (Hkv, bs, D)
+        v = (v_ref[0].astype(jnp.float32) if v_dim is None
+             else k[..., :v_dim])
+        tile = _vpu_tile if q.shape[1] == 1 else _mxu_tile
+        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new, l_blk, pv = tile(q, k, v, cols, m_prev, length=length)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        v = (v_ref[0, 0].astype(jnp.float32) if v_dim is None
-             else k[:, :v_dim])
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + l_blk
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(b == n_b - 1)
     def _finish():
         # length-0 rows never accumulate: l stays 0 -> output exactly 0
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths, *,
@@ -151,6 +199,12 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths, *,
     """q: (N, Hkv, G, D); k_pool/v_pool: (P, Hkv, bs, D); block_tables:
     (N, MB) int32 pool block ids per row; lengths: (N,) int32; ``scale``
     multiplies the scores.  Returns (N, Hkv, G, D).
+
+    Grid (N, MB): one step per row and table entry, over all KV heads
+    (a K/V block of (1, Hkv, bs, D), the query and output blocks (1, Hkv,
+    G, D)).  The tables are held at each row's last valid entry
+    (``_held_tables``), so steps past its length fetch nothing.  G = 1
+    runs the tile on the VPU, G > 1 on the MXU (``_paged_kernel``).
 
     Latent mode (``v_pool`` None, ``v_dim`` set): the values are the
     leading ``v_dim`` lanes of each K tile; returns (N, Hkv, G, v_dim).
@@ -161,33 +215,35 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths, *,
     N, Hkv, G, D = q.shape
     bs = k_pool.shape[-2]
     MB = block_tables.shape[1]
-    grid = (N, Hkv, MB)
+    grid = (N, MB)
     Dv = D if v_pool is not None else v_dim
 
     kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, n_b=MB,
                                v_dim=None if v_pool is not None else v_dim)
-    prefetch = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32)]
+    lengths = lengths.astype(jnp.int32)
+    prefetch = [_held_tables(block_tables.astype(jnp.int32), lengths, bs=bs),
+                lengths]
     if layer is None:
-        kv_spec = pl.BlockSpec((1, 1, bs, D),
-                               lambda i, h, b, tbl, lens: (tbl[i, b], h, 0, 0))
+        kv_spec = pl.BlockSpec((1, Hkv, bs, D),
+                               lambda i, b, tbl, lens: (tbl[i, b], 0, 0, 0))
     else:
         prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
         kv_spec = pl.BlockSpec(
-            (None, 1, 1, bs, D),
-            lambda i, h, b, tbl, lens, lyr: (lyr[0], tbl[i, b], h, 0, 0))
+            (None, 1, Hkv, bs, D),
+            lambda i, b, tbl, lens, lyr: (lyr[0], tbl[i, b], 0, 0, 0))
         kernel = functools.partial(_skip_layer_ref, kernel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # block_tables, lengths[, layer]
+        num_scalar_prefetch=len(prefetch),  # held tables, lengths[, layer]
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda i, h, b, *_: (i, h, 0, 0)),
+            pl.BlockSpec((1, Hkv, G, D), lambda i, b, *_: (i, 0, 0, 0)),
             kv_spec,
         ] + ([kv_spec] if v_pool is not None else []),
-        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda i, h, b, *_: (i, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, Dv), lambda i, b, *_: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, Dv), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, Dv), jnp.float32),
         ],
     )
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
@@ -196,7 +252,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, Hkv, G, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*prefetch, q, *pools)
 

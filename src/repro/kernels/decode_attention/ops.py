@@ -60,10 +60,15 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool,
     that layer in place.
 
     On TPU the Pallas kernel streams only the table-addressed pool
-    blocks (no dense gather); elsewhere the pure-jnp gather reference
-    runs (the kernel's scalar-prefetch indirection is a TPU
-    memory-system question — interpret mode would re-derive the
-    reference semantics through a full pool gather anyway).
+    blocks (no dense gather): one grid step per (row, table entry) moves
+    the block's K/V for every KV head at once; past a row's length the
+    block index stays at its last valid entry, so nothing more is
+    fetched.  With one query per KV head the tile's scores and ``p . v``
+    are VPU reductions, with several they are MXU dots; both in float32.
+    Elsewhere the pure-jnp gather reference runs (the kernel's
+    scalar-prefetch indirection is a TPU memory-system question —
+    interpret mode would re-derive the reference semantics through a full
+    pool gather anyway).
     """
     N, Hq, D = q.shape
     Hkv = k_pool.shape[-3]

@@ -80,28 +80,72 @@ def test_paged_decode_attention_matches_dense():
     assert (np.asarray(paged)[~active] == 0).all()  # masked rows: exact 0
 
 
-def test_paged_kernel_interpret_matches_ref():
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("past", ["random", "nan"])
+@pytest.mark.parametrize("hkv,g,d", [(2, 3, 16), (16, 1, 64), (16, 1, 128),
+                                     (8, 3, 64)],
+                         ids=["small-gqa", "m6", "olmoe", "gqa"])
+def test_paged_kernel_interpret_matches_ref(hkv, g, d, past, dtype):
     """The Pallas paged kernel (scalar-prefetched block table) in
-    interpret mode against the gather reference."""
+    interpret mode against the gather reference, at M6's and OLMoE's one
+    query per KV head and at GQA widths.  ``past="random"``: every table
+    entry a random block; ``"nan"``: the entries past each row's last
+    valid block point at a NaN-filled block, which the kernel must never
+    let into its output (the reference reads it through a table whose
+    entries there are finite)."""
     from repro.kernels.decode_attention.kernel import paged_decode_attention_kernel
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref
 
     rng = np.random.default_rng(1)
-    N, Hkv, G, D, bs, P, MB = 6, 2, 3, 16, 8, 10, 4
+    N, Hkv, G, D, bs, P, MB = 6, hkv, g, d, 8, 10, 4
     q = rng.standard_normal((N, Hkv * G, D)).astype(np.float32)
     k_pool = rng.standard_normal((P, Hkv, bs, D)).astype(np.float32)
     v_pool = rng.standard_normal((P, Hkv, bs, D)).astype(np.float32)
     tables = rng.integers(0, P, size=(N, MB)).astype(np.int32)
     lengths = np.array([0, 1, 9, 17, 32, 25], np.int32)
+    ref_tables = tables
+    if past == "nan":
+        # block boundaries short of the table's end, a mid-block end, empty
+        lengths = np.array([0, 1, 8, 13, 16, 25], np.int32)
+        nan_block = np.full((1, Hkv, bs, D), np.nan, np.float32)
+        k_pool = np.concatenate([k_pool, nan_block])
+        v_pool = np.concatenate([v_pool, nan_block])
+        past_entries = np.arange(MB)[None, :] >= np.maximum(
+            -(-lengths // bs), 1)[:, None]
+        tables = np.where(past_entries, P, tables).astype(np.int32)
+    dt = jnp.dtype(dtype)
+    q, k_pool, v_pool = (jnp.asarray(x, dt) for x in (q, k_pool, v_pool))
 
     out = paged_decode_attention_kernel(
-        jnp.asarray(q).reshape(N, Hkv, G, D), jnp.asarray(k_pool),
-        jnp.asarray(v_pool), jnp.asarray(tables), jnp.asarray(lengths),
-        scale=D ** -0.5, interpret=True).reshape(N, Hkv * G, D)
-    ref = paged_decode_attention_ref(jnp.asarray(q), jnp.asarray(k_pool),
-                                     jnp.asarray(v_pool), jnp.asarray(tables),
+        q.reshape(N, Hkv, G, D), k_pool, v_pool, jnp.asarray(tables),
+        jnp.asarray(lengths), scale=D ** -0.5,
+        interpret=True).reshape(N, Hkv * G, D)
+    ref = paged_decode_attention_ref(q, k_pool, v_pool,
+                                     jnp.asarray(ref_tables),
                                      jnp.asarray(lengths), scale=D ** -0.5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+    assert np.isfinite(out).all() and np.isfinite(ref).all()
+    if dtype == "float32":
+        np.testing.assert_allclose(out, ref, atol=1e-5)
+    else:  # both round the same f32 result to bf16: at most an ulp apart
+        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=2 ** -7)
+
+
+def test_paged_kernel_holds_last_valid_block():
+    """The kernel reads its tables held at each row's last valid block:
+    entry ``min(b, last valid block)`` (an empty row: entry 0), so no
+    entry past a row's length is ever read, and past the length the
+    block index repeats, so no DMA is issued."""
+    from repro.kernels.decode_attention.kernel import _held_tables
+
+    bs, MB = 8, 4
+    lengths = np.array([0, 1, 8, 9, 16, 32], np.int32)
+    tables = np.arange(len(lengths) * MB, dtype=np.int32).reshape(-1, MB)
+    held = np.asarray(_held_tables(jnp.asarray(tables), jnp.asarray(lengths),
+                                   bs=bs))
+    for i, length in enumerate(lengths):
+        last = max(-(-int(length) // bs) - 1, 0)
+        assert list(held[i]) == [tables[i, min(b, last)] for b in range(MB)]
 
 
 # ---------------------------------------------------------------------------

@@ -86,6 +86,25 @@ def test_paged_attention_compiles(one_chip, widths):
         one_chip, *shapes))
 
 
+# the batch cells' serving steps at their real sizes: (rows, q heads, kv
+# heads, head_dim, pool blocks, block, table entries)
+BATCH_STEPS = {"olmoe-batch": (304, 16, 16, 128, 769, 128, 32),
+               "m6-batch": (160, 16, 16, 64, 257, 128, 2)}
+
+
+@pytest.mark.parametrize("cell", sorted(BATCH_STEPS))
+def test_paged_attention_compiles_at_batch_cells(one_chip, cell):
+    """One grid step holds a whole pool block, all 16 KV heads of it:
+    the (1, 16, 128, D) K and V tiles must keep the tiling rule and fit
+    the scoped VMEM."""
+    n, hq, hkv, d, blocks, bs, mb = BATCH_STEPS[cell]
+    pool = ((blocks, hkv, bs, d), jnp.bfloat16)
+    _assert_kernel(_compile_text(
+        functools.partial(paged_decode_attention_kernel, scale=d ** -0.5),
+        one_chip, ((n, hkv, hq // hkv, d), jnp.bfloat16), pool, pool,
+        ((n, mb), jnp.int32), ((n,), jnp.int32)))
+
+
 def test_latent_paged_attention_compiles(one_chip):
     """Moonlight's absorbed decode: 16 query heads over one latent KV
     head of 512 + 64 lanes (zero-padded to 640) in blocks of 256, the
